@@ -224,7 +224,7 @@ def render_utilization():
     with open(path) as f:
         r = json.load(f)
     peak = r.get("peak")
-    if peak is None:  # pre-§15 ops.json artifact: no cost-model columns
+    if peak is None:  # off the chip: utilization not measured
         return None
     sh = r["shape"]
     out = [
@@ -251,8 +251,7 @@ def render_utilization():
         )
     out.append(
         "\n(utilization = achieved FLOP/s or GB/s over the binding "
-        "roofline resource; calibrated CPU ceilings are achievable-not-"
-        "peak, so treat CPU percentages as relative — compare on TPU. "
+        "roofline resource, against the chip's published peak. "
         "The gap to 100% on non-fused ops is the fused-kernel ROADMAP "
         "headroom.)"
     )

@@ -573,7 +573,9 @@ def bench_ops(rows):
 
     base = get_config("hla-1b", reduced=True)
     B, n, steps = 4, 256, 16
-    peak = device_peak()
+    # utilization divides by the chip's published peak; off the chip it is
+    # not measured
+    peak = device_peak() if jax.default_backend() == "tpu" else None
     entries = {}
     for name in seq_op.registered_op_names():
         cfg = base.replace(mixer=("softmax" if name == "attn" else name))
@@ -620,8 +622,11 @@ def bench_ops(rows):
         cost_f = costs.model_cost(cfg, mode="train_fwd", seq_len=n, batch=B)
         cost_d = costs.model_cost(cfg, mode="decode_step", seq_len=n + steps,
                                   batch=B)
-        util_f = roofline_utilization(train_tok_s, cost_f, peak)
-        util_d = roofline_utilization(decode_tok_s, cost_d, peak)
+        nm = {"utilization": None, "bound": "not measured"}
+        util_f = util_d = nm
+        if peak is not None:
+            util_f = roofline_utilization(train_tok_s, cost_f, peak)
+            util_d = roofline_utilization(decode_tok_s, cost_d, peak)
 
         entries[name] = {
             "train_fwd_tok_per_s": round(train_tok_s, 1),
@@ -630,9 +635,9 @@ def bench_ops(rows):
             "decode_iqr_us": round(t_dec.iqr_us, 1),
             "train_flops_per_token": round(cost_f.flops_per_token),
             "decode_flops_per_token": round(cost_d.flops_per_token),
-            "train_util": round(util_f["utilization"], 6),
+            "train_util": util_f["utilization"],
             "train_bound": util_f["bound"],
-            "decode_util": round(util_d["utilization"], 6),
+            "decode_util": util_d["utilization"],
             "decode_bound": util_d["bound"],
             "state_bytes": cost_f.state_bytes,
             "streaming": op.streaming,
@@ -643,8 +648,8 @@ def bench_ops(rows):
             f"ops/{name}", t_fwd.us, t_fwd.iqr_us,
             f"train_fwd_tok_per_s={train_tok_s:.0f} "
             f"decode_tok_per_s={decode_tok_s:.0f} "
-            f"train_util={util_f['utilization']:.4f} "
-            f"decode_util={util_d['utilization']:.4f}",
+            f"train_util={util_f['utilization']} "
+            f"decode_util={util_d['utilization']}",
         ))
         _metric(rows, f"ops/{name}/train_fwd_tok_per_s", train_tok_s,
                 unit="tok/s", direction="higher",
@@ -792,9 +797,10 @@ def bench_distributed(rows):
 
     The subprocess drives the REAL sharded train step
     (``distributed.steps.make_train_step`` on a ("data", "model") mesh
-    from ``launch.mesh.make_mesh``) over the reduced paper model.  On CPU
-    host devices the absolute numbers are smoke-level; the per-device
-    ratio tracks sharding overhead.
+    from ``launch.mesh.make_mesh``) over the reduced paper model.  The
+    children are CPU-only: a child that reached for the chip would find it
+    held by this process.  The absolute numbers are smoke-level; the
+    per-device ratio tracks sharding overhead.
     """
     import subprocess
     import sys
@@ -847,6 +853,9 @@ def bench_distributed(rows):
     entries = []
     for ndev in (1, 8):
         env = dict(os.environ)
+        # the children run on forced host devices by design; keep them off
+        # any accelerator, which the parent process may already hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
         env["PYTHONPATH"] = os.path.join(
             os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"
@@ -899,6 +908,7 @@ def main(argv=None) -> None:
 
     from repro.obs import Obs
     from repro.obs.perf import BenchHistory, profile_capture
+    from repro.runtime.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(
         prog="python -m benchmarks.run",
@@ -918,6 +928,7 @@ def main(argv=None) -> None:
     unknown = [n for n in names if n not in BENCHES]
     if unknown:
         raise SystemExit(f"unknown benches {unknown}; have {list(BENCHES)}")
+    enable_compile_cache()
     rows = RowSink()
     obs = Obs()
     with profile_capture(args.profile_dir, obs=obs):

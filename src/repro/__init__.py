@@ -1,21 +1,7 @@
 """repro — Higher-order Linear Attention, production-scale jax/pallas.
 
-Importing the package configures jax for sharding-invariant numerics:
-
-* ``jax_threefry_partitionable=True`` — without it, ``jax.random.*`` values
-  drawn under ``jit`` depend on the *output sharding* XLA assigns (the
-  legacy threefry lowering materializes per-shard counters), so the same
-  init key produced different parameters on a (2, 4) mesh than on a single
-  device — the root cause of the pjit-vs-single-device training divergence
-  (tests/test_distributed.py).  The partitionable form makes every draw a
-  pure function of (key, position), identical under any mesh.
+Importing the package changes no JAX setting.  Random draws under ``jit``
+do not depend on the output sharding because ``jax_threefry_partitionable``
+is on by default in the installed JAX, so the same init key gives the same
+parameters on any mesh (tests/test_distributed.py).
 """
-
-try:
-    import jax as _jax
-except ModuleNotFoundError:
-    # stdlib-only tools (obs.perfcheck, obs.validate) run on bare CI
-    # python with no jax; everything else fails at its own jax import
-    pass
-else:
-    _jax.config.update("jax_threefry_partitionable", True)

@@ -97,12 +97,24 @@ def donated_aliases(hlo_text: str) -> Dict[int, str]:
     }
 
 
+# source-location debug info in compiled HLO text: the FileNames /
+# FunctionNames / FileLocations / StackFrames tables (header lines and their
+# numbered rows) and each instruction's ``metadata={...}``.  It records
+# where the caller traced from, not what the program computes.
+_DEBUG_TABLE_RE = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)$|^\d+ "
+)
+_METADATA_RE = re.compile(r",? ?metadata=\{[^{}]*\}")
+
+
 def hlo_fingerprint(hlo_text: str) -> str:
-    """sha256 of the HLO with comment lines stripped — two lowerings are
-    "the same program" iff their fingerprints match."""
+    """sha256 of the HLO with comments and source-location debug info
+    stripped — two lowerings are "the same program" iff their
+    fingerprints match."""
     lines = [
-        ln.rstrip() for ln in hlo_text.splitlines()
+        _METADATA_RE.sub("", ln.rstrip()) for ln in hlo_text.splitlines()
         if ln.strip() and not ln.strip().startswith("//")
+        and not _DEBUG_TABLE_RE.match(ln)
     ]
     return hashlib.sha256("\n".join(lines).encode()).hexdigest()
 

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
-
 
 def _quantize(x):
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -81,7 +79,7 @@ def make_compressed_grad_allreduce(mesh, axis_name: str = "data"):
         return red.reshape(g.shape), new_e.reshape(g.shape)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name)),

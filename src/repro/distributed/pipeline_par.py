@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
-
 
 def pipelined_forward(
     layer_fn,  # (layer_params, x) -> x   (one layer)
@@ -39,11 +37,11 @@ def pipelined_forward(
     M = x_microbatches.shape[0]
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(params_local, xs):
         # params_local: (L/S, ...) this stage's layers; xs: (M, mb, n, d)

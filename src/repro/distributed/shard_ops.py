@@ -29,7 +29,6 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 from .sharding import _current_mesh
 
 
@@ -86,7 +85,7 @@ def call_sharded(fn, *args, mesh=None, out_ndims=None):
         )
     else:
         out_specs = jax.tree.map(lambda nd: _row_spec(axes, nd), out_ndims)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*args)

@@ -4,7 +4,8 @@
 tuple of logical names into a PartitionSpec against a concrete mesh,
 dropping (a) mesh axes that don't exist (single-pod meshes have no "pod")
 and (b) assignments whose dimension is not divisible by the axis size
-(e.g. 24 heads on a 16-wide model axis -> replicate).
+(e.g. 24 heads on a 16-wide model axis -> replicate), or whose shards
+would not be a multiple of ``SHARD_MULTIPLE``.
 """
 
 from __future__ import annotations
@@ -41,6 +42,14 @@ RULES = {
     None: None,
 }
 
+# A logical axis sharded only when each shard keeps a multiple of this many
+# entries.  The TPU tiles the second-minor dim in 8 rows; with a sequence
+# shard that is not a multiple of 8 (300 tokens over 4 devices = 75), XLA's
+# TPU compiler refuses the bitcast between the sequence-sharded residual
+# stream and its feature-sharded projections ("Bitcast cannot have
+# different shape sizes").  Such a sequence stays replicated.
+SHARD_MULTIPLE = {"seq": 8}
+
 
 def _axes_for(name, mesh: Mesh, dim: int, rules=None) -> Optional[Tuple[str, ...]]:
     rules = rules or RULES
@@ -52,16 +61,14 @@ def _axes_for(name, mesh: Mesh, dim: int, rules=None) -> Optional[Tuple[str, ...
     present = tuple(a for a in cand if a in mesh.axis_names)
     if not present:
         return None
-    size = int(np.prod([mesh.shape[a] for a in present]))
-    if dim % size != 0:
-        # try shrinking from the left (drop "pod" first etc.)
-        for i in range(1, len(present)):
-            sub = present[i:]
-            size = int(np.prod([mesh.shape[a] for a in sub]))
-            if dim % size == 0:
-                return sub
-        return None
-    return present
+    multiple = SHARD_MULTIPLE.get(name, 1)
+    # drop mesh axes from the left ("pod" first etc.) until the dim splits
+    for i in range(len(present)):
+        sub = present[i:]
+        size = int(np.prod([mesh.shape[a] for a in sub]))
+        if dim % (size * multiple) == 0:
+            return sub
+    return None
 
 
 def spec_for(axes, shape, mesh: Mesh, rules=None) -> P:

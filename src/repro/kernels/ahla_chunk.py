@@ -22,7 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .chunk_math import ahla_chunk_math
-from .hla2_chunk import _compiler_params, _pad_chunk_multiple
+from .hla2_chunk import _compiler_params, _pad_chunk_multiple, gamma_rows
 
 
 def _unscale_padded_state(Pa, Ea, gamma, pad: int):
@@ -40,7 +40,7 @@ def _unscale_padded_state(Pa, Ea, gamma, pad: int):
 
 
 def _ahla_chunk_kernel(
-    gamma_ref,  # (1, 1)
+    gamma_ref,  # (1, 1, 1)
     q_ref,  # (1, w, d)
     k_ref,  # (1, w, d)
     v_ref,  # (1, w, dv)
@@ -78,7 +78,7 @@ def _ahla_chunk_kernel(
     Q = q_ref[0].astype(f32)
     K = k_ref[0].astype(f32)
     V = v_ref[0].astype(f32)
-    g = gamma_ref[0, 0].astype(f32) if has_decay else jnp.ones((), f32)
+    g = gamma_ref[0] if has_decay else jnp.ones((), f32)  # (1, 1)
 
     state0 = (P[...], E[...])
     if save_states:
@@ -106,7 +106,7 @@ def ahla_chunk_pallas(
     chunk: int = 128,
     normalize: bool = False,
     eps: float = 1e-6,
-    interpret: bool | None = None,
+    interpret: bool = False,
     save_chunk_states: bool = False,
     initial_state=None,
 ):
@@ -127,15 +127,9 @@ def ahla_chunk_pallas(
         q, k, v = _pad_chunk_multiple(n, w, q, k, v)
     np_ = n + pad
     nc = np_ // w
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     has_decay = gamma is not None
     has_init = initial_state is not None
-    gamma_in = (
-        jnp.ones((BH, 1), jnp.float32)
-        if gamma is None
-        else gamma.reshape(BH, 1).astype(jnp.float32)
-    )
+    gamma_in = gamma_rows(gamma, BH)
     kernel = functools.partial(
         _ahla_chunk_kernel,
         w=w,
@@ -153,7 +147,7 @@ def ahla_chunk_pallas(
     ]
     grid = (BH, nc)
     in_specs = [
-            pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, c: (i, 0, 0)),
             pl.BlockSpec((1, w, d), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, w, d), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, w, dv), lambda i, c: (i, c, 0)),
@@ -216,7 +210,7 @@ def ahla_chunk_pallas(
 
 
 def _ahla_chunk_bwd_kernel(
-    gamma_ref,  # (1, 1)
+    gamma_ref,  # (1, 1, 1)
     q_ref,  # (1, w, d)  — chunk nc-1-c (reversed walk)
     k_ref,
     v_ref,
@@ -226,7 +220,7 @@ def _ahla_chunk_bwd_kernel(
     dq_ref,
     dk_ref,
     dv_ref,
-    dg_ref,  # (1, 1)
+    dg_ref,  # (1, 1, 1)
     dP,  # scratch (d, dv+1) f32 — state cotangents
     dE,  # scratch (d, dv+1) f32
     dg_acc,  # scratch (1, 1) f32
@@ -254,13 +248,13 @@ def _ahla_chunk_bwd_kernel(
     dstate1 = (dP[...], dE[...])
 
     if has_decay:
-        g = gamma_ref[0, 0].astype(f32)
+        g = gamma_ref[0]  # (1, 1)
         _, vjp = jax.vjp(
             functools.partial(ahla_chunk_math, normalize=normalize, eps=eps),
             Q, K, V, state0, g,
         )
         dQ, dK, dV, dstate0, dgc = vjp((dO, dstate1))
-        dg_acc[0, 0] += dgc
+        dg_acc[...] += dgc
     else:
         one = jnp.ones((), f32)
         _, vjp = jax.vjp(
@@ -278,7 +272,7 @@ def _ahla_chunk_bwd_kernel(
 
     @pl.when(c == n_chunks - 1)
     def _write_dg():
-        dg_ref[0, 0] = dg_acc[0, 0]
+        dg_ref[0] = dg_acc[...]
 
 
 def ahla_chunk_bwd_pallas(
@@ -292,7 +286,7 @@ def ahla_chunk_bwd_pallas(
     chunk: int = 128,
     normalize: bool = False,
     eps: float = 1e-6,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Fused AHLA backward (reverse chunk walk).  Returns (dq, dk, dv, dgamma)."""
     BH, n, d = q.shape
@@ -307,14 +301,8 @@ def ahla_chunk_bwd_pallas(
         "chunk_states do not match the (padded) chunk grid; pass the tuple "
         "returned by ahla_chunk_pallas(save_chunk_states=True) unchanged"
     )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     has_decay = gamma is not None
-    gamma_in = (
-        jnp.ones((BH, 1), jnp.float32)
-        if gamma is None
-        else gamma.reshape(BH, 1).astype(jnp.float32)
-    )
+    gamma_in = gamma_rows(gamma, BH)
     kernel = functools.partial(
         _ahla_chunk_bwd_kernel,
         w=w,
@@ -327,7 +315,7 @@ def ahla_chunk_bwd_pallas(
     rev_blk = lambda i, c: (i, nc - 1 - c, 0)  # noqa: E731
     rev_st = lambda i, c: (i, nc - 1 - c, 0, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
+        pl.BlockSpec((1, 1, 1), lambda i, c: (i, 0, 0)),
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, dv_), rev_blk),
@@ -339,13 +327,13 @@ def ahla_chunk_bwd_pallas(
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, dv_), rev_blk),
-        pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
+        pl.BlockSpec((1, 1, 1), lambda i, c: (i, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((BH, np_, d), q.dtype),
         jax.ShapeDtypeStruct((BH, np_, d), k.dtype),
         jax.ShapeDtypeStruct((BH, np_, dv_), v.dtype),
-        jax.ShapeDtypeStruct((BH, 1), jnp.float32),
+        jax.ShapeDtypeStruct((BH, 1, 1), jnp.float32),
     ]
     scratch_shapes = [
         pltpu.VMEM((d, dv_ + 1), jnp.float32),
@@ -363,5 +351,5 @@ def ahla_chunk_bwd_pallas(
         compiler_params=_compiler_params(interpret),
     )(gamma_in, q, k, v, *chunk_states, do)
     dq, dk, dv = dq[:, :n], dk[:, :n], dv[:, :n]
-    dgamma = dg[:, 0] if has_decay else None
+    dgamma = dg[:, 0, 0] if has_decay else None
     return dq, dk, dv, dgamma

@@ -29,10 +29,14 @@ import jax.numpy as jnp
 
 
 def decay_mats(w: int, g, dtype):
-    """In-kernel L_gamma, g^(t+1), g^(w-1-t) from scalar g (g=1 => plain L).
+    """In-kernel L_gamma, g^(t+1), g^(w-1-t) from g (g=1 => plain L).
 
-    Returns ``(Lg, pow_t, pow_rev, mask)``.  The masked exponent is clamped
-    to 0 before ``exp`` so reverse-mode AD never sees an overflowed branch.
+    ``g`` is a scalar or a ``(1, 1)`` tile (the kernels keep per-row decay
+    as a 2D block; Mosaic has no scalar loads from VMEM).  Returns
+    ``(Lg, pow_t, pow_rev, mask)`` with ``pow_t``/``pow_rev`` as ``(w, 1)``
+    columns: every operand stays 2D and every iota is integer (Mosaic
+    refuses float iotas).  The masked exponent is clamped to 0 before
+    ``exp`` so reverse-mode AD never sees an overflowed branch.
     """
     t = jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
@@ -40,7 +44,7 @@ def decay_mats(w: int, g, dtype):
     diff = jnp.where(mask, t - j, 0).astype(dtype)
     logg = jnp.log(g)
     Lg = jnp.where(mask, jnp.exp(diff * logg), jnp.zeros((), dtype))
-    tv = jax.lax.iota(dtype, w)
+    tv = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0).astype(dtype)
     pow_t = jnp.exp((tv + 1.0) * logg)  # g^t for t=1..w
     pow_rev = jnp.exp((w - 1.0 - tv) * logg)  # g^(w-t) for t=1..w
     return Lg, pow_t, pow_rev, mask
@@ -87,23 +91,23 @@ def hla2_chunk_math(
     QS0Q = mmT(QS0, Q) * Lg
 
     D0 = mm(S0, C0) - G0  # (d, dv)
-    T1 = (pow_t**2)[:, None] * mm(Q, D0)
-    T2 = pow_t[:, None] * mm(QS0Q, V)
+    T1 = pow_t**2 * mm(Q, D0)
+    T2 = pow_t * mm(QS0Q, V)
     T3 = mm(M3, V)
     num = T1 + T2 + T3
     if lam:
         Wqq = mmT(Q, Q) * Lg
-        num = num + lam * (pow_t[:, None] * mm(Q, C0) + mm(Wqq, V))
+        num = num + lam * (pow_t * mm(Q, C0) + mm(Wqq, V))
     if normalize:
         d0v = mm(S0, m0.T) - h0.T  # (d, 1)
         den = (
-            (pow_t**2)[:, None] * mm(Q, d0v)
-            + pow_t[:, None] * jnp.sum(QS0Q, -1, keepdims=True)
+            pow_t**2 * mm(Q, d0v)
+            + pow_t * jnp.sum(QS0Q, -1, keepdims=True)
             + jnp.sum(M3, -1, keepdims=True)
         )
         if lam:
             den = den + lam * (
-                pow_t[:, None] * mm(Q, m0.T) + jnp.sum(Wqq, -1, keepdims=True)
+                pow_t * mm(Q, m0.T) + jnp.sum(Wqq, -1, keepdims=True)
             )
         o = num / (den + eps)
     else:
@@ -111,16 +115,20 @@ def hla2_chunk_math(
 
     # ---- carry update (monoid, B = whole chunk) ----
     rho = jnp.exp(jnp.log(g) * w)
-    Kg = pow_rev[:, None] * K
-    Qg = pow_rev[:, None] * Q
+    Kg = pow_rev * K
+    Qg = pow_rev * Q
     Sw = dot(Kg, K, (((0,), (0,)), ((), ())))  # (d, d)
     Cw = dot(Qg, V, (((0,), (0,)), ((), ())))  # (d, dv)
     mw = jnp.sum(Qg, 0, keepdims=True)  # (1, d)
     N = mmT(K, Q) * Ls
-    Vg = pow_rev[:, None] * V
+    Vg = pow_rev * V
     NVg = mm(N, Vg)
     Gw = dot(Kg, NVg, (((0,), (0,)), ((), ())))
-    Nmg = jnp.sum(N * pow_rev[None, :], -1, keepdims=True)  # (w, 1)
+    # sum_j N[t, j] g^(w-j): the row of exponents comes from its own
+    # integer iota (no (w, 1) -> (1, w) relayout in the kernel)
+    jv = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1).astype(f32)
+    pow_rev_row = jnp.exp((w - 1.0 - jv) * jnp.log(g))
+    Nmg = jnp.sum(N * pow_rev_row, -1, keepdims=True)  # (w, 1)
     hw = dot(Nmg, Kg, (((0,), (0,)), ((), ())))  # (1, d)
 
     S1 = rho * S0 + Sw
@@ -159,15 +167,15 @@ def ahla_chunk_math(
 
     A = mmT(Q, K) * Lg
     AV = mm(A, Vb)  # local first-order outputs
-    r = pow_t[:, None] * mm(Q, P0) + AV  # carry-inclusive r_t | s_t
-    o_aug = pow_t[:, None] * mm(Q, E0) + mm(A, r)
+    r = pow_t * mm(Q, P0) + AV  # carry-inclusive r_t | s_t
+    o_aug = pow_t * mm(Q, E0) + mm(A, r)
     if normalize:
         o = o_aug[:, :-1] / (o_aug[:, -1:] + eps)
     else:
         o = o_aug[:, :-1]
 
     rho = jnp.exp(jnp.log(g) * w)
-    Kg = pow_rev[:, None] * K
+    Kg = pow_rev * K
     KgT_ = lambda X: dot(Kg, X, (((0,), (0,)), ((), ())))  # noqa: E731
     R = dot(K, Q, (((0,), (0,)), ((), ())))  # (d, d) sum_t k_t q_t^T (undecayed)
     P1 = rho * P0 + KgT_(Vb)

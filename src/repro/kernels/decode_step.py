@@ -25,19 +25,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .hla2_chunk import _state_shapes
+from .hla2_chunk import _state_shapes, gamma_rows
 
 
 def _step_compiler_params(interpret: bool):
     if interpret:
         return None
-    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=("parallel",))
 
-    _CP = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return _CP(dimension_semantics=("parallel",))
+
+def _step_gamma(gamma, batch_shape, BH: int):
+    """Decay broadcast over the batch dims, as ``(BH, 1, 1)`` rows."""
+    if gamma is not None:
+        gamma = jnp.broadcast_to(jnp.asarray(gamma, jnp.float32), batch_shape)
+    return gamma_rows(gamma, BH)
 
 
 def _dot(a, b):
@@ -64,7 +67,7 @@ def _outer(a, b):  # a.T @ b  with a (1, d), b (1, e) -> (d, e)
 
 
 def _hla2_step_kernel(
-    gamma_ref,  # (1, 1) f32
+    gamma_ref,  # (1, 1, 1) f32
     q_ref,  # (1, 1, d)
     k_ref,  # (1, 1, d)
     v_ref,  # (1, 1, dv)
@@ -89,7 +92,7 @@ def _hla2_step_kernel(
     q = q_ref[0].astype(f32)  # (1, d)
     k = k_ref[0].astype(f32)
     v = v_ref[0].astype(f32)
-    g = gamma_ref[0, 0].astype(f32) if has_decay else jnp.ones((), f32)
+    g = gamma_ref[0] if has_decay else jnp.ones((), f32)  # (1, 1)
 
     S0, C0, m0, G0, h0 = (
         S_ref[0], C_ref[0], m_ref[0], G_ref[0], h_ref[0]
@@ -134,7 +137,7 @@ def hla2_step_pallas(
     normalize: bool = False,
     eps: float = 1e-6,
     lam: float = 0.0,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """One fused decode step for all rows.  Returns ``(new_state, o_t)``
     (same order as ``core.hla2.hla2_step``)."""
@@ -145,17 +148,9 @@ def hla2_step_pallas(
     BH = 1
     for s in batch_shape:
         BH *= s
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     has_decay = gamma is not None
     f32 = jnp.float32
-    gamma_in = (
-        jnp.ones((BH, 1), f32)
-        if gamma is None
-        else jnp.broadcast_to(
-            jnp.asarray(gamma, f32), batch_shape
-        ).reshape(BH, 1)
-    )
+    gamma_in = _step_gamma(gamma, batch_shape, BH)
     qf = q_t.reshape(BH, 1, d)
     kf = k_t.reshape(BH, 1, d)
     vf = v_t.reshape(BH, 1, dv)
@@ -175,7 +170,7 @@ def hla2_step_pallas(
     st_shapes = _state_shapes(d, dv)
     row = lambda a, b: pl.BlockSpec((1, a, b), lambda i: (i, 0, 0))  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i: (i, 0)),  # gamma
+        row(1, 1),  # gamma
         row(1, d), row(1, d), row(1, dv),
     ] + [row(a, b) for a, b in st_shapes]
     out_specs = [row(1, dv)] + [row(a, b) for a, b in st_shapes]
@@ -209,7 +204,7 @@ def hla2_step_pallas(
 
 
 def _ahla_step_kernel(
-    gamma_ref,  # (1, 1)
+    gamma_ref,  # (1, 1, 1)
     q_ref,  # (1, 1, d)
     k_ref,  # (1, 1, d)
     vb_ref,  # (1, 1, dv+1)  ones-augmented value
@@ -229,7 +224,7 @@ def _ahla_step_kernel(
     q = q_ref[0].astype(f32)
     k = k_ref[0].astype(f32)
     vb = vb_ref[0].astype(f32)
-    g = gamma_ref[0, 0].astype(f32) if has_decay else jnp.ones((), f32)
+    g = gamma_ref[0] if has_decay else jnp.ones((), f32)  # (1, 1)
 
     P1 = g * P_ref[0] + _outer(k, vb)  # [P | m] update (Algorithm 2)
     rbar = _dot(q, P1)  # [r_t | s_t], inclusive P (Thm 6.1)
@@ -256,7 +251,7 @@ def ahla_step_pallas(
     *,
     normalize: bool = False,
     eps: float = 1e-6,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """One fused AHLA decode step for all rows.  Returns ``(new_state, o_t)``."""
     R, P, m, E, n = state
@@ -266,17 +261,9 @@ def ahla_step_pallas(
     BH = 1
     for s in batch_shape:
         BH *= s
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     has_decay = gamma is not None
     f32 = jnp.float32
-    gamma_in = (
-        jnp.ones((BH, 1), f32)
-        if gamma is None
-        else jnp.broadcast_to(
-            jnp.asarray(gamma, f32), batch_shape
-        ).reshape(BH, 1)
-    )
+    gamma_in = _step_gamma(gamma, batch_shape, BH)
     qf = q_t.reshape(BH, 1, d)
     kf = k_t.reshape(BH, 1, d)
     vb = jnp.concatenate(
@@ -297,7 +284,7 @@ def ahla_step_pallas(
     )
     row = lambda a, b: pl.BlockSpec((1, a, b), lambda i: (i, 0, 0))  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i: (i, 0)),
+        row(1, 1),  # gamma
         row(1, d), row(1, d), row(1, dv + 1),
         row(d, d), row(d, dv + 1), row(d, dv + 1),
     ]

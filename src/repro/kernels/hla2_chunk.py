@@ -44,10 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .chunk_math import decay_mats, hla2_chunk_math
-
-# Back-compat alias (ahla_chunk and older call sites import it from here).
-_decay_mats = decay_mats
+from .chunk_math import hla2_chunk_math
 
 
 def _state_shapes(d: int, dv: int):
@@ -57,10 +54,21 @@ def _state_shapes(d: int, dv: int):
 def _compiler_params(interpret: bool):
     if interpret:
         return None
-    _CP = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return _CP(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def gamma_rows(gamma, BH: int):
+    """Per-row decay as a ``(BH, 1, 1)`` f32 array (ones when None).
+
+    Each grid row reads its own ``(1, 1, 1)`` block: the block's last two
+    dims equal the array's, which is what the TPU's (8, 128) tiling rule
+    asks of blocks narrower than a tile.  Kernels use the ``(1, 1)`` tile
+    as a broadcasting operand — never as a scalar, which Mosaic cannot load
+    from or store to VMEM.
+    """
+    if gamma is None:
+        return jnp.ones((BH, 1, 1), jnp.float32)
+    return gamma.reshape(BH, 1, 1).astype(jnp.float32)
 
 
 def _pad_chunk_multiple(n: int, w: int, *arrays):
@@ -96,7 +104,7 @@ def _unscale_padded_state(state, gamma, pad: int):
 
 def _hla2_chunk_kernel(
     # inputs: gamma, q/k/v, then the initial carry (5) iff has_init
-    gamma_ref,  # (1, 1) f32
+    gamma_ref,  # (1, 1, 1) f32
     q_ref,  # (1, w, d)
     k_ref,  # (1, w, d)
     v_ref,  # (1, w, dv)
@@ -143,10 +151,7 @@ def _hla2_chunk_kernel(
     Q = q_ref[0].astype(f32)  # (w, d)
     K = k_ref[0].astype(f32)
     V = v_ref[0].astype(f32)
-    if has_decay:
-        g = gamma_ref[0, 0].astype(f32)
-    else:
-        g = jnp.ones((), f32)
+    g = gamma_ref[0] if has_decay else jnp.ones((), f32)  # (1, 1)
 
     state0 = (S[...], C[...], m[...], G[...], h[...])
     if save_states:
@@ -183,7 +188,7 @@ def hla2_chunk_pallas(
     normalize: bool = False,
     eps: float = 1e-6,
     lam: float = 0.0,
-    interpret: bool | None = None,
+    interpret: bool = False,
     save_chunk_states: bool = False,
     initial_state=None,
 ):
@@ -200,6 +205,9 @@ def hla2_chunk_pallas(
     Arbitrary ``n``: inputs are zero-padded up to a chunk multiple and the
     output sliced back to ``n`` (the checkpoint tuple keeps the padded
     chunk count — feed it unchanged to ``hla2_chunk_bwd_pallas``).
+
+    ``interpret=True`` runs the kernel body in Python (CPU tests); the
+    default compiles it for the TPU and fails elsewhere.
     """
     BH, n, d = q.shape
     dv = v.shape[-1]
@@ -209,14 +217,9 @@ def hla2_chunk_pallas(
         q, k, v = _pad_chunk_multiple(n, w, q, k, v)
     np_ = n + pad
     nc = np_ // w
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     has_decay = gamma is not None
     has_init = initial_state is not None
-    if gamma is None:
-        gamma_in = jnp.ones((BH, 1), jnp.float32)
-    else:
-        gamma_in = gamma.reshape(BH, 1).astype(jnp.float32)
+    gamma_in = gamma_rows(gamma, BH)
 
     kernel = functools.partial(
         _hla2_chunk_kernel,
@@ -240,7 +243,7 @@ def hla2_chunk_pallas(
     )
     grid = (BH, nc)
     in_specs = [
-            pl.BlockSpec((1, 1), lambda i, c: (i, 0)),  # gamma
+            pl.BlockSpec((1, 1, 1), lambda i, c: (i, 0, 0)),  # gamma
             pl.BlockSpec((1, w, d), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, w, d), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, w, dv), lambda i, c: (i, c, 0)),
@@ -294,7 +297,7 @@ def hla2_chunk_pallas(
 
 def _hla2_chunk_bwd_kernel(
     # inputs
-    gamma_ref,  # (1, 1) f32
+    gamma_ref,  # (1, 1, 1) f32
     q_ref,  # (1, w, d)   — chunk nc-1-c (reversed walk)
     k_ref,
     v_ref,
@@ -308,7 +311,7 @@ def _hla2_chunk_bwd_kernel(
     dq_ref,  # (1, w, d)
     dk_ref,
     dv_ref,
-    dg_ref,  # (1, 1) f32
+    dg_ref,  # (1, 1, 1) f32
     # scratch: reverse-mode state cotangents + dgamma accumulator
     dS,  # (d, d) f32
     dC,  # (d, dv)
@@ -346,7 +349,7 @@ def _hla2_chunk_bwd_kernel(
     dstate1 = (dS[...], dC[...], dm[...], dG[...], dh[...])
 
     if has_decay:
-        g = gamma_ref[0, 0].astype(f32)
+        g = gamma_ref[0]  # (1, 1)
         _, vjp = jax.vjp(
             functools.partial(
                 hla2_chunk_math, normalize=normalize, eps=eps, lam=lam
@@ -354,7 +357,7 @@ def _hla2_chunk_bwd_kernel(
             Q, K, V, state0, g,
         )
         dQ, dK, dV, dstate0, dgc = vjp((dO, dstate1))
-        dg_acc[0, 0] += dgc
+        dg_acc[...] += dgc
     else:
         one = jnp.ones((), f32)
         _, vjp = jax.vjp(
@@ -372,7 +375,7 @@ def _hla2_chunk_bwd_kernel(
 
     @pl.when(c == n_chunks - 1)
     def _write_dg():
-        dg_ref[0, 0] = dg_acc[0, 0]
+        dg_ref[0] = dg_acc[...]
 
 
 def hla2_chunk_bwd_pallas(
@@ -387,7 +390,7 @@ def hla2_chunk_bwd_pallas(
     normalize: bool = False,
     eps: float = 1e-6,
     lam: float = 0.0,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Fused backward: reverse chunk walk with checkpointed states.
 
@@ -405,14 +408,8 @@ def hla2_chunk_bwd_pallas(
         "chunk_states do not match the (padded) chunk grid; pass the tuple "
         "returned by hla2_chunk_pallas(save_chunk_states=True) unchanged"
     )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     has_decay = gamma is not None
-    gamma_in = (
-        jnp.ones((BH, 1), jnp.float32)
-        if gamma is None
-        else gamma.reshape(BH, 1).astype(jnp.float32)
-    )
+    gamma_in = gamma_rows(gamma, BH)
 
     kernel = functools.partial(
         _hla2_chunk_bwd_kernel,
@@ -428,7 +425,7 @@ def hla2_chunk_bwd_pallas(
     rev_blk = lambda i, c: (i, nc - 1 - c, 0)  # noqa: E731
     rev_st = lambda i, c: (i, nc - 1 - c, 0, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i, c: (i, 0)),  # gamma
+        pl.BlockSpec((1, 1, 1), lambda i, c: (i, 0, 0)),  # gamma
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, dv_), rev_blk),
@@ -441,13 +438,13 @@ def hla2_chunk_bwd_pallas(
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, d), rev_blk),
         pl.BlockSpec((1, w, dv_), rev_blk),
-        pl.BlockSpec((1, 1), lambda i, c: (i, 0)),  # dgamma
+        pl.BlockSpec((1, 1, 1), lambda i, c: (i, 0, 0)),  # dgamma
     ]
     out_shape = [
         jax.ShapeDtypeStruct((BH, np_, d), q.dtype),
         jax.ShapeDtypeStruct((BH, np_, d), k.dtype),
         jax.ShapeDtypeStruct((BH, np_, dv_), v.dtype),
-        jax.ShapeDtypeStruct((BH, 1), jnp.float32),
+        jax.ShapeDtypeStruct((BH, 1, 1), jnp.float32),
     ]
     scratch_shapes = [pltpu.VMEM(s, jnp.float32) for s in state_shapes]
     scratch_shapes.append(pltpu.VMEM((1, 1), jnp.float32))
@@ -462,5 +459,5 @@ def hla2_chunk_bwd_pallas(
         compiler_params=_compiler_params(interpret),
     )(gamma_in, q, k, v, *chunk_states, do)
     dq, dk, dv = dq[:, :n], dk[:, :n], dv[:, :n]
-    dgamma = dg[:, 0] if has_decay else None
+    dgamma = dg[:, 0, 0] if has_decay else None
     return dq, dk, dv, dgamma

@@ -19,8 +19,9 @@ passes of training:
   (``jax.vjp`` over the pure-jnp chunkwise reference — a second,
   XLA-scheduled forward whose carried state round-trips through HBM).
 * ``use_pallas=False`` falls back to the reference end to end (used on CPU
-  training runs; the kernels themselves are exercised in interpret mode by
-  the tests).
+  training runs).  ``interpret=True`` runs the kernels in Pallas interpret
+  mode (how the CPU tests exercise them); by default they compile for the
+  TPU, and fail on any other backend rather than fall back in silence.
 
 Arbitrary sequence lengths are supported: the kernel wrappers zero-pad to
 a chunk multiple and slice the results back.
@@ -60,10 +61,11 @@ def _merge_bh(x):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10)
 )
 def _hla2_fwd_core(
-    q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd
+    q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd,
+    interpret,
 ):
     if use_pallas:
         qf, B, H = _merge_bh(q)
@@ -71,7 +73,8 @@ def _hla2_fwd_core(
         vf, _, _ = _merge_bh(v)
         gf = None if gamma is None else gamma.reshape(B * H)
         o, _ = hla2_chunk_pallas(
-            qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps, lam=lam
+            qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps, lam=lam,
+            interpret=interpret,
         )
         return o.reshape(q.shape[:2] + o.shape[1:])
     o, _ = _ref.hla2_chunk_ref(
@@ -81,7 +84,8 @@ def _hla2_fwd_core(
 
 
 def _hla2_vjp_fwd(
-    q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd
+    q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd,
+    interpret,
 ):
     if use_pallas and fused_bwd:
         # fused training path: forward checkpoints per-chunk incoming states
@@ -92,17 +96,19 @@ def _hla2_vjp_fwd(
         gf = None if gamma is None else gamma.reshape(B * H)
         o, _, chunk_states = hla2_chunk_pallas(
             qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps,
-            lam=lam, save_chunk_states=True,
+            lam=lam, save_chunk_states=True, interpret=interpret,
         )
         out = o.reshape(q.shape[:2] + o.shape[1:])
         return out, (q, k, v, gamma, chunk_states)
     out = _hla2_fwd_core(
-        q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd
+        q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd,
+        interpret,
     )
     return out, (q, k, v, gamma, None)
 
 
-def _hla2_vjp_bwd(chunk, normalize, eps, lam, use_pallas, fused_bwd, res, g):
+def _hla2_vjp_bwd(chunk, normalize, eps, lam, use_pallas, fused_bwd,
+                  interpret, res, g):
     q, k, v, gamma, chunk_states = res
 
     if use_pallas and fused_bwd:
@@ -114,7 +120,7 @@ def _hla2_vjp_bwd(chunk, normalize, eps, lam, use_pallas, fused_bwd, res, g):
         gf = None if gamma is None else gamma.reshape(B * H)
         dq, dk, dv, dgamma = hla2_chunk_bwd_pallas(
             qf, kf, vf, gf, gof, chunk_states, chunk=chunk,
-            normalize=normalize, eps=eps, lam=lam,
+            normalize=normalize, eps=eps, lam=lam, interpret=interpret,
         )
         unmerge = lambda x, p: x.reshape(p.shape).astype(p.dtype)  # noqa: E731
         dgamma = (
@@ -144,11 +150,12 @@ _hla2_fwd_core.defvjp(_hla2_vjp_fwd, _hla2_vjp_bwd)
 def hla2_attention(
     q, k, v, gamma=None, *, chunk: int = 128, normalize: bool = False,
     eps: float = 1e-6, lam: float = 0.0, use_pallas: bool = True,
-    fused_bwd: bool = True,
+    fused_bwd: bool = True, interpret: bool = False,
 ):
     """Masked second-order HLA over (B, H, n, d) tensors (fused fwd + bwd)."""
     return _hla2_fwd_core(
-        q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd
+        q, k, v, gamma, chunk, normalize, eps, lam, use_pallas, fused_bwd,
+        interpret,
     )
 
 
@@ -157,16 +164,17 @@ def hla2_attention(
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _ahla_fwd_core(q, k, v, gamma, chunk, normalize, eps, use_pallas,
-                   fused_bwd):
+                   fused_bwd, interpret):
     if use_pallas:
         qf, B, H = _merge_bh(q)
         kf, _, _ = _merge_bh(k)
         vf, _, _ = _merge_bh(v)
         gf = None if gamma is None else gamma.reshape(B * H)
         o, _ = ahla_chunk_pallas(
-            qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps
+            qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps,
+            interpret=interpret,
         )
         return o.reshape(q.shape[:2] + o.shape[1:])
     o, _ = _ref.ahla_chunk_ref(
@@ -176,7 +184,7 @@ def _ahla_fwd_core(q, k, v, gamma, chunk, normalize, eps, use_pallas,
 
 
 def _ahla_vjp_fwd(q, k, v, gamma, chunk, normalize, eps, use_pallas,
-                  fused_bwd):
+                  fused_bwd, interpret):
     if use_pallas and fused_bwd:
         TRACE_COUNTS["ahla_fwd_fused"] += 1
         qf, B, H = _merge_bh(q)
@@ -185,17 +193,19 @@ def _ahla_vjp_fwd(q, k, v, gamma, chunk, normalize, eps, use_pallas,
         gf = None if gamma is None else gamma.reshape(B * H)
         o, _, chunk_states = ahla_chunk_pallas(
             qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps,
-            save_chunk_states=True,
+            save_chunk_states=True, interpret=interpret,
         )
         out = o.reshape(q.shape[:2] + o.shape[1:])
         return out, (q, k, v, gamma, chunk_states)
     out = _ahla_fwd_core(
-        q, k, v, gamma, chunk, normalize, eps, use_pallas, fused_bwd
+        q, k, v, gamma, chunk, normalize, eps, use_pallas, fused_bwd,
+        interpret,
     )
     return out, (q, k, v, gamma, None)
 
 
-def _ahla_vjp_bwd(chunk, normalize, eps, use_pallas, fused_bwd, res, g):
+def _ahla_vjp_bwd(chunk, normalize, eps, use_pallas, fused_bwd, interpret,
+                  res, g):
     q, k, v, gamma, chunk_states = res
 
     if use_pallas and fused_bwd:
@@ -207,7 +217,7 @@ def _ahla_vjp_bwd(chunk, normalize, eps, use_pallas, fused_bwd, res, g):
         gf = None if gamma is None else gamma.reshape(B * H)
         dq, dk, dv, dgamma = ahla_chunk_bwd_pallas(
             qf, kf, vf, gf, gof, chunk_states, chunk=chunk,
-            normalize=normalize, eps=eps,
+            normalize=normalize, eps=eps, interpret=interpret,
         )
         unmerge = lambda x, p: x.reshape(p.shape).astype(p.dtype)  # noqa: E731
         dgamma = (
@@ -235,10 +245,12 @@ _ahla_fwd_core.defvjp(_ahla_vjp_fwd, _ahla_vjp_bwd)
 def ahla_attention(
     q, k, v, gamma=None, *, chunk: int = 128, normalize: bool = False,
     eps: float = 1e-6, use_pallas: bool = True, fused_bwd: bool = True,
+    interpret: bool = False,
 ):
     """AHLA over (B, H, n, d) tensors (fused fwd + bwd)."""
     return _ahla_fwd_core(
-        q, k, v, gamma, chunk, normalize, eps, use_pallas, fused_bwd
+        q, k, v, gamma, chunk, normalize, eps, use_pallas, fused_bwd,
+        interpret,
     )
 
 
@@ -258,7 +270,7 @@ def ahla_attention(
 def hla2_prefill(
     q, k, v, gamma=None, *, state: HLA2State | None = None,
     chunk: int = 128, normalize: bool = False, eps: float = 1e-6,
-    lam: float = 0.0, use_pallas: bool = True,
+    lam: float = 0.0, use_pallas: bool = True, interpret: bool = False,
 ):
     """Chunk-parallel HLA2 prefill over (B, H, n, d).  Returns
     ``(o, HLA2State)`` — the state decodes onward via ``hla2_decode_step``."""
@@ -282,7 +294,7 @@ def hla2_prefill(
         )
     o, (S, C, m, G, h) = hla2_chunk_pallas(
         qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps, lam=lam,
-        initial_state=init,
+        initial_state=init, interpret=interpret,
     )
     o = o.reshape(q.shape[:2] + o.shape[1:])
     unm = lambda x: x.reshape((B, H) + x.shape[1:])  # noqa: E731
@@ -292,7 +304,7 @@ def hla2_prefill(
 def ahla_prefill(
     q, k, v, gamma=None, *, state: AHLAState | None = None,
     chunk: int = 128, normalize: bool = False, eps: float = 1e-6,
-    use_pallas: bool = True,
+    use_pallas: bool = True, interpret: bool = False,
 ):
     """Chunk-parallel AHLA prefill over (B, H, n, d).  Returns
     ``(o, AHLAState)``.  The undecayed cross moment ``R`` (scan-only
@@ -319,7 +331,7 @@ def ahla_prefill(
         )
     o, (P, m, E, n) = ahla_chunk_pallas(
         qf, kf, vf, gf, chunk=chunk, normalize=normalize, eps=eps,
-        initial_state=init,
+        initial_state=init, interpret=interpret,
     )
     o = o.reshape(q.shape[:2] + o.shape[1:])
     unm = lambda x: x.reshape((B, H) + x.shape[1:])  # noqa: E731
@@ -335,7 +347,7 @@ def ahla_prefill(
 def hla2_decode_step(
     state: HLA2State, q_t, k_t, v_t, gamma=None, *,
     normalize: bool = False, eps: float = 1e-6, lam: float = 0.0,
-    use_pallas: bool = True,
+    use_pallas: bool = True, interpret: bool = False,
 ):
     """One fused decode token over (..., d) rows.  Returns ``(state, o_t)``."""
     if not use_pallas:
@@ -348,7 +360,7 @@ def hla2_decode_step(
     TRACE_COUNTS["hla2_decode_step"] += 1
     new_state, o = hla2_step_pallas(
         tuple(state), q_t, k_t, v_t, gamma, normalize=normalize, eps=eps,
-        lam=lam,
+        lam=lam, interpret=interpret,
     )
     return HLA2State(*new_state), o
 
@@ -356,6 +368,7 @@ def hla2_decode_step(
 def ahla_decode_step(
     state: AHLAState, q_t, k_t, v_t, gamma=None, *,
     normalize: bool = False, eps: float = 1e-6, use_pallas: bool = True,
+    interpret: bool = False,
 ):
     """One fused AHLA decode token.  Returns ``(state, o_t)``."""
     if not use_pallas:
@@ -366,6 +379,7 @@ def ahla_decode_step(
         )
     TRACE_COUNTS["ahla_decode_step"] += 1
     new_state, o = ahla_step_pallas(
-        tuple(state), q_t, k_t, v_t, gamma, normalize=normalize, eps=eps
+        tuple(state), q_t, k_t, v_t, gamma, normalize=normalize, eps=eps,
+        interpret=interpret,
     )
     return AHLAState(*new_state), o

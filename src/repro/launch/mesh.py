@@ -4,7 +4,7 @@ jax device state (required by the dry-run contract)."""
 from __future__ import annotations
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,7 +16,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the model shards through logical
+    ``constrain`` calls that GSPMD propagates, which Explicit axes (the
+    JAX >= 0.7 default) reject at the first unannotated gather."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(shape)
+    )
 
 
 def make_mesh(shape=None, axes=None):
@@ -35,7 +44,7 @@ def make_mesh(shape=None, axes=None):
                 break
         shape = (n // model, model)
         axes = ("data", "model")
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _auto_mesh(shape, axes)
 
 
 def mesh_summary(mesh) -> str:

@@ -19,7 +19,9 @@ faults from the ``runtime.faults`` catalog — e.g.
 decode block (quarantine), ``--inject drafter.propose@0+`` crashes the
 drafter every round (circuit breaker -> plain fallback).  ``--deadline-s``
 gives every request a wall-clock budget; expired requests finish with
-``status="timeout"``.  The summary line counts terminal statuses.
+``status="timeout"``.  The summary line counts terminal statuses; without
+``--inject``, a request that ends in ``error`` makes the command exit
+nonzero.
 
 ``HOST_DEVICES=N`` simulates an N-device host mesh (like launch.train);
 params and slot states then come up sharded via the same
@@ -51,6 +53,7 @@ from ..distributed import sharding as shd  # noqa: E402
 from ..models import lm  # noqa: E402
 from ..models.param import init_params  # noqa: E402
 from ..obs import JsonlSink, Obs, profile_capture, write_metrics  # noqa: E402
+from ..runtime.compile_cache import enable_compile_cache  # noqa: E402
 from ..runtime.faults import FaultPlan, parse_fault  # noqa: E402
 from ..serving import (  # noqa: E402
     Engine,
@@ -143,6 +146,7 @@ def main(argv=None):
                          "wall-clock stamps")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced, mixer=args.mixer)
     mesh = make_mesh()
     print(f"[serve] {cfg.name} on {mesh_summary(mesh)}")
@@ -287,6 +291,13 @@ def main(argv=None):
         if args.metrics_out:
             write_metrics(engine.obs.snapshot(), args.metrics_out)
             print(f"[serve] metrics -> {args.metrics_out}")
+    failed = [r for r in results if r.status == "error"]
+    if failed and not args.inject:
+        raise SystemExit(
+            f"[serve] {len(failed)} of {len(results)} requests failed with "
+            f"no injected fault; first: request {failed[0].rid}: "
+            f"{failed[0].error}"
+        )
     return len(results)
 
 

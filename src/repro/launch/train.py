@@ -32,6 +32,7 @@ from ..distributed import steps as steps_mod  # noqa: E402
 from ..models.param import init_params  # noqa: E402
 from ..obs import JsonlSink, Obs, profile_capture, write_metrics  # noqa: E402
 from ..optim import adamw  # noqa: E402
+from ..runtime.compile_cache import enable_compile_cache  # noqa: E402
 from ..runtime.faults import FaultPlan, FaultSpec  # noqa: E402
 from ..runtime.ft import FaultTolerantLoop  # noqa: E402
 from .mesh import make_mesh, mesh_summary  # noqa: E402
@@ -68,6 +69,7 @@ def main(argv=None):
                          "stream carry matching wall-clock stamps")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced, mixer=args.mixer)
     mesh = make_mesh()
     print(f"[train] {cfg.name} on {mesh_summary(mesh)}")
@@ -86,11 +88,14 @@ def main(argv=None):
         opt_state = jax.jit(
             adamw.init_opt_state, out_shardings=opt_shard
         )(params)
+        # params and optimizer state are donated: the step updates them in
+        # place, so the device holds one copy of each, not two
         step_fn = jax.jit(
             steps_mod.make_train_step(
                 cfg, opt_cfg, microbatches=args.microbatches,
                 grad_shardings=pshard,
-            )
+            ),
+            donate_argnums=(0, 1),
         )
 
         stream = SyntheticStream(

@@ -96,7 +96,6 @@ def embed_apply(p, ids):
 
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.compat import shard_map
     from ..distributed.sharding import spec_for
 
     # adaptive batch spec: shard_map in_specs are strict about
@@ -109,7 +108,7 @@ def embed_apply(p, ids):
     bspec = idspec[0] if len(idspec) else None
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("model", None), idspec),
         out_specs=P(*((bspec,) + (None,) * ids.ndim)),

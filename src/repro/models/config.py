@@ -34,9 +34,9 @@ class HLAConfig:
     fused_bwd: bool = True  # fused Pallas backward with chunk-level state
     #   checkpointing (DESIGN.md §3); False = legacy recompute-in-backward
     #   (second unfused forward under jax.vjp — slower, slightly less HBM)
-    force_pallas: bool = False  # run the Pallas kernels even off-TPU
-    #   (interpret mode) — used by the distributed tests/CI to exercise the
-    #   shard_map'd fused path on host devices; never the perf default
+    force_pallas: bool = False  # run the Pallas kernels in interpret mode
+    #   on any backend — how the CPU tests exercise the shard_map'd fused
+    #   path on host devices; never set on the chip
 
 
 @dataclasses.dataclass(frozen=True)

@@ -98,10 +98,15 @@ def _out_norm(p, o, cfg, eps=1e-6):
 
 def _pallas_enabled(hc) -> bool:
     """Fused Pallas kernels: native on TPU; elsewhere only when
-    ``force_pallas`` opts into interpret mode (distributed tests/CI)."""
+    ``force_pallas`` asks for them in interpret mode (CPU tests)."""
     return hc.use_pallas and (
         jax.default_backend() == "tpu" or hc.force_pallas
     )
+
+
+def _kernel_kw(hc, kw):
+    """Kernel keywords: ``kw`` plus the interpret mode the config asks for."""
+    return dict(kw, interpret=hc.force_pallas)
 
 
 # output ranks for shard_ops.call_sharded (avoids an eval_shape re-trace
@@ -124,7 +129,7 @@ def _hla2_fwd(q, k, v, gamma, hc, *, state, want_state, kw):
         # one chunk-parallel kernel call prefills the whole prompt and
         # hands back the exact streaming state (Section-4 identity)
         fn = functools.partial(
-            kops.hla2_prefill, chunk=hc.chunk, lam=hc.lam, **kw
+            kops.hla2_prefill, chunk=hc.chunk, lam=hc.lam, **_kernel_kw(hc, kw)
         )
         return shard_ops.call_sharded(
             lambda q_, k_, v_, g_, s_: fn(q_, k_, v_, g_, state=s_),
@@ -135,7 +140,7 @@ def _hla2_fwd(q, k, v, gamma, hc, *, state, want_state, kw):
         o = shard_ops.call_sharded(
             functools.partial(
                 kops.hla2_attention, chunk=hc.chunk, lam=hc.lam,
-                fused_bwd=hc.fused_bwd, **kw
+                fused_bwd=hc.fused_bwd, **_kernel_kw(hc, kw)
             ),
             q, k, v, gamma, out_ndims=4,
         )
@@ -148,7 +153,9 @@ def _hla2_fwd(q, k, v, gamma, hc, *, state, want_state, kw):
 def _hla2_step(state, q1, k1, v1, gamma, hc, kw):
     if _pallas_enabled(hc):
         return shard_ops.call_sharded(
-            functools.partial(kops.hla2_decode_step, lam=hc.lam, **kw),
+            functools.partial(
+                kops.hla2_decode_step, lam=hc.lam, **_kernel_kw(hc, kw)
+            ),
             state, q1, k1, v1, gamma,
             out_ndims=(_HLA2_STATE_NDIMS, 3),
         )
@@ -159,7 +166,9 @@ def _ahla_fwd(q, k, v, gamma, hc, *, state, want_state, kw):
     if hc.impl == "scan":
         return core_ahla.ahla_scan(q, k, v, gamma, state=state, **kw)
     if _pallas_enabled(hc) and (want_state or state is not None):
-        fn = functools.partial(kops.ahla_prefill, chunk=hc.chunk, **kw)
+        fn = functools.partial(
+            kops.ahla_prefill, chunk=hc.chunk, **_kernel_kw(hc, kw)
+        )
         return shard_ops.call_sharded(
             lambda q_, k_, v_, g_, s_: fn(q_, k_, v_, g_, state=s_),
             q, k, v, gamma, state,
@@ -169,7 +178,7 @@ def _ahla_fwd(q, k, v, gamma, hc, *, state, want_state, kw):
         o = shard_ops.call_sharded(
             functools.partial(
                 kops.ahla_attention, chunk=hc.chunk,
-                fused_bwd=hc.fused_bwd, **kw
+                fused_bwd=hc.fused_bwd, **_kernel_kw(hc, kw)
             ),
             q, k, v, gamma, out_ndims=4,
         )
@@ -182,7 +191,7 @@ def _ahla_fwd(q, k, v, gamma, hc, *, state, want_state, kw):
 def _ahla_step(state, q1, k1, v1, gamma, hc, kw):
     if _pallas_enabled(hc):
         return shard_ops.call_sharded(
-            functools.partial(kops.ahla_decode_step, **kw),
+            functools.partial(kops.ahla_decode_step, **_kernel_kw(hc, kw)),
             state, q1, k1, v1, gamma,
             out_ndims=(_AHLA_STATE_NDIMS, 3),
         )

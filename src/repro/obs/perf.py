@@ -2,8 +2,9 @@
 
 Three pieces, all consumed by ``benchmarks/run.py`` and CI:
 
-* **Roofline** — ``device_peak()`` (known-accelerator table, calibrated
-  matmul fallback) and ``roofline_utilization(tok_per_s, cost, peak)``
+* **Roofline** — ``device_peak()`` (one table of published accelerator
+  peaks; any other device is an error) and
+  ``roofline_utilization(tok_per_s, cost, peak)``
   which turns a measured throughput plus a ``repro.obs.costs`` OpCost
   into achieved FLOP/s, achieved GB/s, utilization fractions and the
   bound (compute vs memory) — the §Utilization table in
@@ -45,9 +46,10 @@ BENCH_SCHEMA = "repro.obs.bench/v1"
 #: direction of goodness for a bench row
 DIRECTIONS = ("higher", "lower")
 
-#: peak dense-f32 FLOP/s and HBM GB/s for accelerators we run on, keyed
-#: by substrings of ``device.device_kind``.  bf16/f32 matmul peak on TPU
-#: (MXU); conservative public numbers.
+#: published per-chip peaks, keyed by substrings of ``device.device_kind``:
+#: bf16 matmul FLOP/s (MXU) and HBM bytes/s.  Source: Google Cloud TPU
+#: documentation, one page per generation ("TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM).
 _KNOWN_PEAKS = (
     ("v6", 918e12, 1640e9),      # TPU v6e (Trillium)
     ("v5p", 459e12, 2765e9),
@@ -58,49 +60,13 @@ _KNOWN_PEAKS = (
     ("v2", 46e12, 700e9),
 )
 
-_cpu_peak_cache: dict = {}
-
-
-def _calibrate_cpu_peak(d: int = 1024, copy_mb: int = 32, repeats: int = 3):
-    """Measure an achievable matmul FLOP/s + copy-bandwidth on this host.
-
-    CPU 'peak' is meaningless from spec sheets under pytest-grade noise;
-    a short calibration gives a *reachable* ceiling so CPU utilization
-    numbers are comparable across runs on the same host.  FLOP/s comes
-    from a BLAS matmul (best-of-N); bytes/s from a large memcpy (read +
-    write counted) — the two ceilings are measured independently because
-    a compute-bound matmul says nothing about memory bandwidth.
-    """
-    import numpy as np
-
-    a = np.random.default_rng(0).standard_normal((d, d), dtype=np.float32)
-    b = np.random.default_rng(1).standard_normal((d, d), dtype=np.float32)
-    a @ b  # warm
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        a @ b
-        best = min(best, time.perf_counter() - t0)
-    flops = 2.0 * d ** 3 / best
-
-    src = np.zeros(copy_mb * (1 << 20) // 4, dtype=np.float32)
-    dst = np.empty_like(src)
-    np.copyto(dst, src)  # warm
-    best_cp = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        np.copyto(dst, src)
-        best_cp = min(best_cp, time.perf_counter() - t0)
-    membw = 2.0 * src.nbytes / best_cp
-    return flops, membw
-
 
 def device_peak(device=None) -> dict:
     """``{"flops_per_s", "bytes_per_s", "kind", "source"}`` for a device.
 
-    Known accelerators come from the table; anything else (CPU, unknown
-    kinds) falls back to a calibrated matmul, marked ``source:
-    "calibrated"`` so readers know the ceiling is achievable-not-peak.
+    Peaks come from the published table only.  A device whose kind is not
+    in it (the CPU, an accelerator not listed) raises: a utilization
+    against a made-up ceiling is not a device metric.
     """
     import jax
 
@@ -112,11 +78,10 @@ def device_peak(device=None) -> dict:
         if key in low:
             return {"flops_per_s": flops, "bytes_per_s": membw,
                     "kind": kind, "source": "table"}
-    if kind not in _cpu_peak_cache:
-        _cpu_peak_cache[kind] = _calibrate_cpu_peak()
-    flops, membw = _cpu_peak_cache[kind]
-    return {"flops_per_s": flops, "bytes_per_s": membw,
-            "kind": kind, "source": "calibrated"}
+    raise ValueError(
+        f"no published peak for device kind {kind!r}; add it to "
+        "repro.obs.perf._KNOWN_PEAKS with its source"
+    )
 
 
 def roofline_utilization(tok_per_s: float, cost, peak: Optional[dict] = None

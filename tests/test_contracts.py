@@ -12,7 +12,7 @@ Two halves:
 
 Everything here is lower-only: no entry point is ever executed.  The
 conftest enables x64 for the fp64 test oracles, so the real entry
-points lower under ``jax.experimental.disable_x64()`` — exactly the
+points lower under ``jax.enable_x64(False)`` — exactly the
 default runtime configuration the contracts describe.
 """
 
@@ -128,7 +128,7 @@ def test_pad_to_bucket():
 
 @pytest.fixture(scope="module")
 def entry_reports():
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         return {r.name: r for r in check_entry_points()}
 
 
@@ -160,7 +160,7 @@ def test_same_bucket_lowers_identically():
     # the recompilation-hazard detector's core claim, asserted directly:
     # two prefills at the same padded length are byte-identical programs
     cfg = default_config()
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         n = pad_to_bucket(5, cfg.hla.chunk)
         fp1 = hlo_fingerprint(prefill_hlo(cfg, prompt_len=n))
         fp2 = hlo_fingerprint(prefill_hlo(cfg, prompt_len=n))

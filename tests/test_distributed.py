@@ -312,13 +312,12 @@ def test_int8_error_feedback_allreduce():
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
-        from repro.distributed.compat import shard_map
         from repro.distributed.compression import int8_allreduce_mean
 
         mesh = make_mesh((8,), ("data",))
         x = np.random.RandomState(0).randn(8, 4096).astype(np.float32)
 
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P("data"), P("data")),
                            out_specs=(P("data"), P("data")))
         def run(xs, es):

@@ -54,7 +54,7 @@ def test_hla2_fused_vjp_matches_naive_grad(rng, use_gamma, normalize, lam):
     def loss_fused(q_, k_, v_, g_):
         o = hla2_attention(
             q_, k_, v_, g_, chunk=8, normalize=normalize, lam=lam,
-            use_pallas=True, fused_bwd=True,
+            interpret=True, fused_bwd=True,
         )
         return jnp.sum(o * do)
 
@@ -89,7 +89,7 @@ def test_ahla_fused_vjp_matches_naive_grad(rng, use_gamma, normalize):
     def loss_fused(q_, k_, v_, g_):
         o = ahla_attention(
             q_, k_, v_, g_, chunk=8, normalize=normalize,
-            use_pallas=True, fused_bwd=True,
+            interpret=True, fused_bwd=True,
         )
         return jnp.sum(o * do)
 
@@ -173,11 +173,12 @@ def test_public_api_arbitrary_length_grads(rng):
 
     def loss(q_, k_, v_, g_, fused):
         o = hla2_attention(
-            q_, k_, v_, g_, chunk=16, use_pallas=fused, fused_bwd=fused
+            q_, k_, v_, g_, chunk=16, use_pallas=fused, fused_bwd=fused,
+            interpret=True,
         )
         return jnp.sum(o * do)
 
-    o_fused = hla2_attention(q, k, v, g, chunk=16, use_pallas=True)
+    o_fused = hla2_attention(q, k, v, g, chunk=16, interpret=True)
     o_ref = hla2_attention(q, k, v, g, chunk=16, use_pallas=False)
     _assert_close(o_fused, o_ref, msg="fwd")
     got = jax.grad(lambda *a: loss(*a, True), argnums=(0, 1, 2, 3))(q, k, v, g)
@@ -195,7 +196,7 @@ def test_fused_bwd_off_matches_fused_bwd_on(rng):
 
     def loss(q_, k_, v_, g_, fused_bwd):
         o = hla2_attention(
-            q_, k_, v_, g_, chunk=8, use_pallas=True, fused_bwd=fused_bwd
+            q_, k_, v_, g_, chunk=8, fused_bwd=fused_bwd, interpret=True
         )
         return jnp.sum(o**2)
 

@@ -100,14 +100,16 @@ def test_ops_wrapper_grads(rng):
     g = jnp.asarray(rng.uniform(0.9, 0.99, (B, H)), jnp.float32)
 
     for fn in (hla2_attention, ahla_attention):
-        o_pallas = fn(q, k, v, g, chunk=8, use_pallas=True)
+        o_pallas = fn(q, k, v, g, chunk=8, interpret=True)
         o_ref = fn(q, k, v, g, chunk=8, use_pallas=False)
         np.testing.assert_allclose(
             np.asarray(o_pallas), np.asarray(o_ref), atol=1e-4, rtol=1e-4
         )
 
         def loss(args, f=fn, pallas=True):
-            return jnp.sum(f(*args, g, chunk=8, use_pallas=pallas) ** 2)
+            return jnp.sum(
+                f(*args, g, chunk=8, use_pallas=pallas, interpret=True) ** 2
+            )
 
         g_pallas = jax.grad(loss)((q, k, v))
         g_ref = jax.grad(lambda a: loss(a, pallas=False))((q, k, v))

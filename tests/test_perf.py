@@ -293,9 +293,16 @@ def test_roofline_compute_vs_memory_bound():
 
 
 def test_device_peak_on_cpu_is_calibrated():
-    peak = device_peak()
-    assert peak["source"] in ("table", "calibrated")
-    assert peak["flops_per_s"] > 0 and peak["bytes_per_s"] > 0
+    """No ceiling is made up for a device outside the published table:
+    the CPU (and any unlisted accelerator) raises instead of calibrating."""
+    with pytest.raises(ValueError, match="no published peak"):
+        device_peak()
+
+    class FakeAccel:
+        device_kind = "TPU v99"
+
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peak(FakeAccel())
 
 
 def test_device_peak_known_table():

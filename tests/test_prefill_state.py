@@ -50,7 +50,7 @@ def test_hla2_prefill_state_matches_serial(rng, n, use_gamma, normalize):
     gamma = g if use_gamma else None
     st_serial = _serial_hla2(q, k, v, gamma, n, normalize=normalize)
     _, st_kernel = kops.hla2_prefill(
-        q, k, v, gamma, chunk=16, normalize=normalize, use_pallas=True
+        q, k, v, gamma, chunk=16, normalize=normalize, interpret=True
     )
     for ref, got, name in zip(st_serial, st_kernel, "SCmGh"):
         err = float(jnp.max(jnp.abs(ref.astype(jnp.float32) - got)))
@@ -65,7 +65,7 @@ def test_ahla_prefill_state_matches_serial(rng, n, use_gamma, normalize):
     gamma = g if use_gamma else None
     st_serial = _serial_ahla(q, k, v, gamma, n, normalize=normalize)
     _, st_kernel = kops.ahla_prefill(
-        q, k, v, gamma, chunk=16, normalize=normalize, use_pallas=True
+        q, k, v, gamma, chunk=16, normalize=normalize, interpret=True
     )
     for ref, got, name in zip(st_serial, st_kernel, ["R", "P", "m", "E", "n"]):
         err = float(jnp.max(jnp.abs(ref.astype(jnp.float32) - got)))
@@ -77,7 +77,7 @@ def test_hla2_prefill_512_token_acceptance(rng):
     (no per-token Python loop) and matches serial hla2_step decode ≤1e-4."""
     n = 512
     q, k, v, g = _mk(rng, 1, 2, n, 8, 8)
-    _, st_kernel = kops.hla2_prefill(q, k, v, g, chunk=128, use_pallas=True)
+    _, st_kernel = kops.hla2_prefill(q, k, v, g, chunk=128, interpret=True)
     st_serial = _serial_hla2(q, k, v, g, n)
     for ref, got, name in zip(st_serial, st_kernel, "SCmGh"):
         err = float(jnp.max(jnp.abs(ref.astype(jnp.float32) - got)))
@@ -91,7 +91,7 @@ def test_hla2_prefill_resumes_from_carry(rng):
     st_half = _serial_hla2(q[:, :, :cut], k[:, :, :cut], v[:, :, :cut], g, cut)
     _, st_resumed = kops.hla2_prefill(
         q[:, :, cut:], k[:, :, cut:], v[:, :, cut:], g, chunk=16,
-        state=st_half, use_pallas=True,
+        state=st_half, interpret=True,
     )
     st_full = _serial_hla2(q, k, v, g, 37)
     for ref, got, name in zip(st_full, st_resumed, "SCmGh"):
@@ -105,7 +105,7 @@ def test_ahla_prefill_resumes_from_carry(rng):
     st_half = _serial_ahla(q[:, :, :cut], k[:, :, :cut], v[:, :, :cut], g, cut)
     _, st_resumed = kops.ahla_prefill(
         q[:, :, cut:], k[:, :, cut:], v[:, :, cut:], g, chunk=16,
-        state=st_half, use_pallas=True,
+        state=st_half, interpret=True,
     )
     st_full = _serial_ahla(q, k, v, g, 37)
     for ref, got, name in zip(st_full, st_resumed, ["R", "P", "m", "E", "n"]):
@@ -127,7 +127,9 @@ def test_hla2_fused_decode_step_matches_jnp(rng, use_gamma):
     for t in range(6):
         args = (q[:, :, t], k[:, :, t], v[:, :, t], gamma)
         st_ref, o_ref = hla2_step(st_ref, *args, lam=0.1)
-        st_ker, o_ker = kops.hla2_decode_step(st_ker, *args, lam=0.1)
+        st_ker, o_ker = kops.hla2_decode_step(
+            st_ker, *args, lam=0.1, interpret=True
+        )
         assert float(jnp.max(jnp.abs(o_ref - o_ker))) <= STATE_TOL
     for ref, got in zip(st_ref, st_ker):
         assert float(jnp.max(jnp.abs(ref - got))) <= STATE_TOL
@@ -142,7 +144,7 @@ def test_ahla_fused_decode_step_matches_jnp(rng, use_gamma):
     for t in range(6):
         args = (q[:, :, t], k[:, :, t], v[:, :, t], gamma)
         st_ref, o_ref = ahla_step(st_ref, *args)
-        st_ker, o_ker = kops.ahla_decode_step(st_ker, *args)
+        st_ker, o_ker = kops.ahla_decode_step(st_ker, *args, interpret=True)
         assert float(jnp.max(jnp.abs(o_ref - o_ker))) <= STATE_TOL
     for ref, got in zip(st_ref, st_ker):
         assert float(jnp.max(jnp.abs(ref - got))) <= STATE_TOL
@@ -152,11 +154,11 @@ def test_decode_step_continues_prefill_state(rng):
     """prefill(prompt) then fused steps == serial steps over prompt+decode."""
     q, k, v, g = _mk(rng, 1, 2, 20, 8, 8)
     _, st = kops.hla2_prefill(
-        q[:, :, :16], k[:, :, :16], v[:, :, :16], g, chunk=8, use_pallas=True
+        q[:, :, :16], k[:, :, :16], v[:, :, :16], g, chunk=8, interpret=True
     )
     for t in range(16, 20):
         st, _ = kops.hla2_decode_step(
-            st, q[:, :, t], k[:, :, t], v[:, :, t], g
+            st, q[:, :, t], k[:, :, t], v[:, :, t], g, interpret=True
         )
     st_serial = _serial_hla2(q, k, v, g, 20)
     for ref, got, name in zip(st_serial, st, "SCmGh"):

@@ -340,7 +340,7 @@ def test_verify_snapshot_restore_host_level_rollback(rng):
     host-level primitives: snapshot_slot -> make_verify (one
     chunk-parallel call) -> on rejection restore_slot + make_replay of
     the accepted prefix.  The rolled-back slot state must equal stepping
-    the accepted tokens through plain decode — bit-for-bit."""
+    the accepted tokens through plain decode, to float32 rounding."""
     from repro.serving.sampling import SamplingConfig
     from repro.serving.spec import make_replay, make_verify
 
@@ -387,8 +387,14 @@ def test_verify_snapshot_restore_host_level_rollback(rng):
             )
             p = p + 1
         got = pool.snapshot_slot(s)
+        # the replay is jitted and the oracle steps eagerly: XLA may
+        # reassociate the fused jitted reductions, so the two agree to a
+        # few float32 ulps (1 ulp of a state entry near 10 is 9.5e-7),
+        # not bit for bit
         for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(got)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6
+            )
     # random drafts against a random model: rejections must have occurred
     assert any(int(packed[s, 0]) < k for s in range(slots))
 
