@@ -591,7 +591,7 @@ class ObsNamingRule(Rule):
     BENCH_RE = re.compile(r"^[a-z][a-z0-9_]*(/[a-z0-9_]+)+$")
     HIST_UNITS = ("_seconds", "_bytes", "_tokens", "_ratio")
     METRIC_METHODS = ("counter", "gauge", "histogram")
-    EVENT_METHODS = ("event", "span", "timer")
+    EVENT_METHODS = ("event", "span", "timer", "anchor")
     BENCH_METHODS = ("bench_row",)
 
     def _bad_metric(self, family: str, name: str) -> Optional[str]:

@@ -193,6 +193,7 @@ def ahla_chunk_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name="ahla_chunk_fwd",
         compiler_params=_compiler_params(interpret),
     )(*inputs)
     o, Pa, Ea = outs[:3]
@@ -348,6 +349,7 @@ def ahla_chunk_bwd_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name="ahla_chunk_bwd",
         compiler_params=_compiler_params(interpret),
     )(gamma_in, q, k, v, *chunk_states, do)
     dq, dk, dv = dq[:, :n], dk[:, :n], dv[:, :n]

@@ -186,6 +186,7 @@ def hla2_step_pallas(
         # state operands update in place in HBM (operand 4..8 -> output 1..5)
         input_output_aliases={4: 1, 5: 2, 6: 3, 7: 4, 8: 5},
         interpret=interpret,
+        name="hla2_decode_step",
         compiler_params=_step_compiler_params(interpret),
     )(gamma_in, qf, kf, vf, Sf, Cf, mf, Gf, hf)
     new_state = (
@@ -303,6 +304,7 @@ def ahla_step_pallas(
         out_shape=out_shape,
         input_output_aliases={4: 1, 5: 2, 6: 3},
         interpret=interpret,
+        name="ahla_decode_step",
         compiler_params=_step_compiler_params(interpret),
     )(gamma_in, qf, kf, vb, Rf, Pbar, Ebar)
     new_state = (

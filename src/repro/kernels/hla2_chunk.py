@@ -280,6 +280,7 @@ def hla2_chunk_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name="hla2_chunk_fwd",
         compiler_params=_compiler_params(interpret),
     )(*inputs)
     o, S, C, m, G, h = outs[:6]
@@ -456,6 +457,7 @@ def hla2_chunk_bwd_pallas(
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name="hla2_chunk_bwd",
         compiler_params=_compiler_params(interpret),
     )(gamma_in, q, k, v, *chunk_states, do)
     dq, dk, dv = dq[:, :n], dk[:, :n], dv[:, :n]

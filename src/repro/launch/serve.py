@@ -142,8 +142,8 @@ def main(argv=None):
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the measured "
                          "traffic (not the warmup) into DIR; "
-                         "profile.start/stop events carry matching "
-                         "wall-clock stamps")
+                         "profile.start/stop anchors tie the trace's "
+                         "clock to the obs stream's")
     args = ap.parse_args(argv)
 
     enable_compile_cache()

@@ -65,8 +65,8 @@ def main(argv=None):
                          "resume events, fired faults")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the whole run "
-                         "into DIR; profile.start/stop events on the obs "
-                         "stream carry matching wall-clock stamps")
+                         "into DIR; profile.start/stop anchors tie the "
+                         "trace's clock to the obs stream's")
     args = ap.parse_args(argv)
 
     enable_compile_cache()

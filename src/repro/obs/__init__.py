@@ -96,6 +96,9 @@ class Obs:
     def event(self, name, **labels):
         self.tracer.event(name, **labels)
 
+    def anchor(self, name, **labels):
+        self.tracer.anchor(name, **labels)
+
     def timer(self, name, **labels) -> SpanTimer:
         return SpanTimer(self.tracer, name, **labels)
 

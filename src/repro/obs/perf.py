@@ -11,10 +11,11 @@ Three pieces, all consumed by ``benchmarks/run.py`` and CI:
   ``benchmarks/report.py``.
 
 * **Profiler capture** — ``profile_capture(profile_dir, obs=...)``
-  wraps a region in ``jax.profiler.start_trace/stop_trace`` and mirrors
-  the boundaries as ``profile.start`` / ``profile.stop`` events on the
-  obs tracer, so the XLA trace timeline can be lined up against the
-  ``repro.obs.events/v1`` spans (both carry wall-clock stamps).  No-op
+  wraps a region in ``jax.profiler.start_trace/stop_trace`` and marks
+  the boundaries with ``profile.start`` / ``profile.stop`` anchors
+  (``Tracer.anchor``: an obs event and a trace annotation at one
+  instant), so the XLA trace timeline can be lined up against the
+  ``repro.obs.events/v1`` spans.  No-op
   when ``profile_dir`` is falsy.  Exposed as ``--profile-dir`` on
   ``launch/train.py``, ``launch/serve.py`` and ``benchmarks/run.py``.
 
@@ -121,9 +122,10 @@ def roofline_utilization(tok_per_s: float, cost, peak: Optional[dict] = None
 def profile_capture(profile_dir, obs=None):
     """``jax.profiler`` trace of the wrapped region, or no-op if falsy.
 
-    Emits ``profile.start`` / ``profile.stop`` events (with wall-clock
-    ``wall_ns`` payloads) on ``obs.trace`` so the captured XLA timeline
-    can be correlated with the obs span stream.
+    Marks both ends with ``Tracer.anchor`` (``profile.start`` /
+    ``profile.stop``): each is an obs event and a zero-length annotation
+    in the trace at one instant, so a span's ``ts`` maps to trace time as
+    ``ts - anchor.ts + start_ns`` of the anchor's annotation.
     """
     if not profile_dir:
         yield None
@@ -133,14 +135,12 @@ def profile_capture(profile_dir, obs=None):
     os.makedirs(profile_dir, exist_ok=True)
     jax.profiler.start_trace(profile_dir)
     if obs is not None:
-        obs.event("profile.start", profile_dir=str(profile_dir),
-                  wall_ns=time.time_ns())
+        obs.anchor("profile.start", profile_dir=str(profile_dir))
     try:
         yield profile_dir
     finally:
         if obs is not None:
-            obs.event("profile.stop", profile_dir=str(profile_dir),
-                      wall_ns=time.time_ns())
+            obs.anchor("profile.stop", profile_dir=str(profile_dir))
         jax.profiler.stop_trace()
 
 

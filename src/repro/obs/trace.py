@@ -35,7 +35,9 @@ the CI validator)::
 
 ``ts`` is ``perf_counter``-relative (monotonic within a process, not an
 epoch) — events are for *ordering and duration*, wall-clock anchoring is
-the sink's job (``JsonlSink`` stamps an epoch offset in its header).
+the sink's job (``JsonlSink`` stamps an epoch offset in its header).  A
+profiler trace counts from its own start instead; ``Tracer.anchor``
+records the one event that ties the two clocks together.
 """
 
 from __future__ import annotations
@@ -45,7 +47,7 @@ import contextlib
 import itertools
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 
 def _trace_annotation(enabled) -> Optional[type]:
@@ -90,11 +92,29 @@ class Tracer:
         rec.update(labels)
         self._record(rec)
 
+    def anchor(self, name: str, **labels) -> None:
+        """Record an event at the instant a zero-length
+        ``jax.profiler.TraceAnnotation`` of the same name opens, so a
+        captured profiler trace and the ring share a clock: a record's
+        ``ts`` maps to trace time as ``ts - anchor.ts + start_ns`` of the
+        anchor's annotation in the xplane.  Forwarded whatever
+        ``annotate`` says (an anchor is only useful inside a trace)."""
+        annotation = _trace_annotation(True)
+        ann = annotation(name) if annotation is not None else None
+        rec = {"kind": "event", "name": name, "ts": time.perf_counter()}
+        rec.update(labels)
+        if ann is not None:
+            ann.__enter__()
+            ann.__exit__(None, None, None)
+        self._record(rec)
+
     @contextlib.contextmanager
     def span(self, name: str, **labels):
         """Record a wall-clock interval; nests (``depth`` = enclosing
-        spans on this thread).  Exceptions propagate — the span is still
-        recorded, flagged ``error=True``."""
+        spans on this thread).  Yields the span's labels, so a label
+        known only at the end (a count, a hit length) is set on the
+        yielded dict inside the block.  Exceptions propagate — the span
+        is still recorded, flagged ``error=True``."""
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
@@ -104,7 +124,7 @@ class Tracer:
         if ann is not None:
             ann.__enter__()
         try:
-            yield
+            yield labels
         except BaseException:
             self._close_span(name, t0, labels, len(stack) - 1, error=True)
             raise
@@ -173,25 +193,3 @@ class SpanTimer:
         rec.update(extra)
         self.tracer._record(rec)
         return dur
-
-
-_NESTING_DOC: Dict[str, str] = {
-    # the span/event catalog each subsystem emits — kept here so the
-    # timeline module and the docs have one source of truth
-    "request.queued": "request entered run()'s pending queue",
-    "request.admitted": "slot assigned, prefill done, first token sampled",
-    "request.first_token": "TTFT endpoint (dur rides request.admitted)",
-    "request.done": "terminal: status in ok|error|timeout|cancelled",
-    "engine.prefill": "chunk-parallel admission prefill (span)",
-    "engine.decode_block": "one step-locked decode block (span)",
-    "engine.spec_round": "one draft->verify->accept round (span)",
-    "train.step": "one optimizer step (span)",
-    "train.resumed": "checkpoint auto-resume on loop entry",
-    "ckpt.save": "one checkpoint save (span, async thread)",
-    "ckpt.restore": "one checkpoint restore (span)",
-    "fault.fired": "a runtime.faults injection point fired",
-    "profile.start": "jax.profiler trace capture opened (perf.py; "
-                     "wall_ns correlates the XLA timeline)",
-    "profile.stop": "jax.profiler trace capture closed",
-    "bench.run": "one bench function in benchmarks/run.py (span)",
-}

@@ -67,7 +67,7 @@ import numpy as np
 from ..models import lm, seq_op
 from ..obs import Obs
 from ..runtime.faults import FaultPlan
-from .cache import PrefixCache
+from .cache import PrefixCache, tree_bytes
 from .sampling import SamplingConfig, sample
 from .scheduler import Scheduler, SchedulerConfig
 from .spec import SpecConfig, build_drafter
@@ -287,10 +287,6 @@ class Engine:
         m = self.obs
         self._m_ttft = m.histogram(
             "serving_ttft_seconds", "admission -> first sampled token")
-        self._m_itl = m.histogram(
-            "serving_inter_token_seconds",
-            "decode block wall-clock / tokens stepped (one observation "
-            "per block/round — never per-token host timing)")
         self._m_prefill_s = m.counter(
             "serving_prefill_seconds_total", "wall-clock in admissions")
         self._m_decode_s = m.counter(
@@ -334,12 +330,6 @@ class Engine:
         self._m_ttft_hit = m.histogram(
             "serving_ttft_hit_seconds",
             "TTFT of admissions resumed from a cached prefix snapshot")
-        self._m_ttft_saved = m.histogram(
-            "serving_cache_ttft_saved_seconds",
-            "estimated prefill wall-clock avoided per cache hit "
-            "(cached prefix tokens x EWMA cold prefill s/token)")
-        # EWMA of cold prefill seconds/token — the TTFT-saved estimator
-        self._prefill_s_per_tok: Optional[float] = None
 
         pool = self.pool
 
@@ -524,6 +514,13 @@ class Engine:
 
     def admit(self, slot: int, req: GenRequest) -> int:
         """Prefill ``req`` into ``slot``; returns the first sampled token.
+        The ``engine.admit`` span covers all of it, from validation to the
+        slot going live (see ``_admit``)."""
+        with self.obs.span("engine.admit", rid=req.rid, slot=slot):
+            return self._admit(slot, req)
+
+    def _admit(self, slot: int, req: GenRequest) -> int:
+        """The admission behind ``admit``.
 
         Cold path: ONE chunk-parallel prefill call + one scatter write.
         With a prefix cache attached (DESIGN.md §16) admission becomes:
@@ -559,10 +556,12 @@ class Engine:
             done = 0  # tokens already summarized into carry_state
             if self.cache is not None:
                 self._bind_faults()  # cache.corrupt may fire in lookup
-                found = self.cache.lookup(prompt_np, max_prefix=L - 1)
-                if found is not None:
-                    hit_len, host_snap = found
-                    done, carry_state = hit_len, host_snap
+                with self.obs.span("engine.cache_lookup") as lab:
+                    found = self.cache.lookup(prompt_np, max_prefix=L - 1)
+                    if found is not None:
+                        hit_len, host_snap = found
+                        done, carry_state = hit_len, host_snap
+                    lab["hit_len"] = hit_len
                 aligned = self.cache.aligned_len(L)
                 if aligned > done:
                     # advance to the chunk-aligned boundary first so its
@@ -606,23 +605,16 @@ class Engine:
                 f"request {req.rid}: admission prefill produced a "
                 "non-finite state — slot quarantined"
             )
-        if insert_at and tree_finite_host(got[2]):
-            # insert-on-prefill-complete, AFTER the health gate: a
-            # poisoned boundary state must never become a cache entry
-            self.cache.insert(prompt_np[:insert_at], got[2])
+        if insert_at:
+            with self.obs.span("engine.cache_insert",
+                               nbytes=tree_bytes(got[2])):
+                # insert-on-prefill-complete, AFTER the health gate: a
+                # poisoned boundary state must never become a cache entry
+                if tree_finite_host(got[2]):
+                    self.cache.insert(prompt_np[:insert_at], got[2])
         first_tok = int(first_host)
         ttft = time.perf_counter() - t0
-        if hit_len:
-            self._m_ttft_hit.observe(ttft)
-            if self._prefill_s_per_tok is not None:
-                self._m_ttft_saved.observe(
-                    hit_len * self._prefill_s_per_tok)
-        else:
-            self._m_ttft_cold.observe(ttft)
-            rate = ttft / L
-            self._prefill_s_per_tok = rate if \
-                self._prefill_s_per_tok is None else (
-                    0.9 * self._prefill_s_per_tok + 0.1 * rate)
+        (self._m_ttft_hit if hit_len else self._m_ttft_cold).observe(ttft)
         self.tokens = self.tokens.at[slot, 0].set(first_tok)
         self.positions = self.positions.at[slot, 0].set(len(prompt_np))
         self.active[slot] = True
@@ -908,17 +900,17 @@ class Engine:
             toks_host, finite_host = jax.device_get(
                 (toks, finite))  # sync-point: the once-per-block transfer
         toks_host = np.asarray(toks_host)
-        dt = time.perf_counter() - t0
-        self._m_decode_s.inc(dt)
-        self._m_itl.observe(dt / n_steps)
-        for s in range(self.pool.slots):
-            if not self.active[s]:
-                continue
-            if not bool(finite_host[s]):
-                self._quarantine(s)
-                continue
-            self._commit(s, toks_host[:, s])
-        self._sweep_deadlines()
+        self._m_decode_s.inc(time.perf_counter() - t0)
+        with self.obs.span("engine.commit",
+                           slots=int(self.active.sum())):
+            for s in range(self.pool.slots):
+                if not self.active[s]:
+                    continue
+                if not bool(finite_host[s]):
+                    self._quarantine(s)
+                    continue
+                self._commit(s, toks_host[:, s])
+            self._sweep_deadlines()
 
     # -- speculative decode -------------------------------------------------
 
@@ -996,7 +988,6 @@ class Engine:
         if any(int(packed_h[s, 0]) < k for s in healthy):
             self._m_spec_replays.inc()  # the rollback arm ran
         accepted_total = 0
-        stepped = 0  # tokens the round advanced (accepted + bonus)
         for s in slots_active:
             if not bool(finite_h[s]):
                 self._quarantine(s)
@@ -1006,7 +997,6 @@ class Engine:
             self._m_spec_drafted.inc(k)
             self._m_spec_accepted.inc(m)
             accepted_total += m
-            stepped += m + 1
             if self._commit(s, committed):
                 continue  # finished: state is stale but the slot is free
             if self.breaker["state"] != "closed":
@@ -1015,9 +1005,8 @@ class Engine:
                 self.drafter.commit(s, committed)
             except Exception as e:
                 self._trip_breaker(f"drafter.commit failed: {e!r}")
-        dt = timer.close(accepted=accepted_total)
+        timer.close(accepted=accepted_total)
         self._m_decode_s.inc(time.perf_counter() - t0)
-        self._m_itl.observe(dt / max(stepped, 1))
         return True, accepted_total
 
     # -- driver -------------------------------------------------------------
@@ -1038,7 +1027,15 @@ class Engine:
         ``sched.stall``, autoscale the usable slot count, admit scheduler
         winners into free slots, advance one decode block.  Never raises
         — every failure becomes a per-request status (the ``run()``
-        while-loop's no-raise contract, CI-enforced, lives here)."""
+        while-loop's no-raise contract, CI-enforced, lives here).  The
+        ``engine.tick`` span covers it, labelled with the admissions it
+        made."""
+        with self.obs.span("engine.tick") as lab:
+            lab["admitted"] = self._tick()
+
+    def _tick(self) -> int:
+        """The body of ``_drive_tick``; returns the number admitted."""
+        n_admitted = 0
         self._bind_faults()
         # queued-deadline expiry FIRST: an expired request must never
         # consume a prefill, and learns its fate THIS tick even when no
@@ -1075,6 +1072,7 @@ class Engine:
                     try:
                         self.admit(s, req)
                         admitted = True
+                        n_admitted += 1
                     except Exception as e:
                         self._fail(req, "error", f"admission failed: {e}")
         if self.active.any():
@@ -1090,6 +1088,7 @@ class Engine:
                             s, status="error",
                             error=f"decode block failed: {e!r}",
                         )
+        return n_admitted
 
     def run(self, requests: List[GenRequest]) -> List[GenResult]:
         """Serve ``requests`` to completion with continuous batching.

@@ -355,9 +355,10 @@ def test_rpr007_metric_shapes(tmp_path):
             "    m.gauge('serving_queue_total', 'h')\n"      # _total on gauge
             "    m.histogram('serving_ttft', 'h')\n"         # no unit
             "    m.histogram('BadName', 'h')\n"              # not snake
-            "    m.event('FooBar')\n",                       # not dotted
+            "    m.event('FooBar')\n"                        # not dotted
+            "    m.anchor('ProfileStart')\n",                # not dotted
     }, rules=["RPR007"])
-    assert [f.line for f in fs] == [2, 3, 4, 5, 6]
+    assert [f.line for f in fs] == [2, 3, 4, 5, 6, 7]
 
 
 def test_rpr007_bench_row_names(tmp_path):
@@ -383,7 +384,8 @@ def test_rpr007_schema_conformant_names_pass(tmp_path):
             "    m.histogram('serving_ttft_seconds', 'h')\n"
             "    obs.event('request.first_token')\n"
             "    obs.span('engine.decode_block')\n"
-            "    obs.timer('engine.spec_round')\n",
+            "    obs.timer('engine.spec_round')\n"
+            "    obs.anchor('profile.start')\n",
     }, rules=["RPR007"])
     assert fs == []
 

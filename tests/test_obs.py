@@ -49,6 +49,7 @@ from repro.obs.validate import (
 from repro.runtime.faults import FaultPlan, FaultSpec
 from repro.runtime.ft import FaultTolerantLoop
 from repro.serving import Engine, GenRequest
+from repro.serving.cache import PrefixCache
 
 
 def _cfg():
@@ -256,6 +257,43 @@ class TestTracer:
         assert header["schema"] == "repro.obs.events/v1"
         assert "epoch_offset" in header
 
+    def test_span_yields_labels_set_at_the_end(self):
+        t = Tracer(annotate=False)
+        with t.span("engine.tick", fixed=1) as lab:
+            lab["admitted"] = 3
+        (rec,) = t.events(kind="span")
+        assert (rec["fixed"], rec["admitted"]) == (1, 3)
+
+    def test_anchor_maps_span_ts_onto_the_profiler_trace(self, tmp_path):
+        """A span's ``ts``, mapped through the ``profile.start`` anchor,
+        lands within 1 ms of its annotation's start in the xplane."""
+        from repro.obs import profile_capture
+
+        obs = Obs(annotate=True)
+        with profile_capture(str(tmp_path), obs=obs):
+            for i in range(3):
+                with obs.span("work.step", i=i):
+                    (jnp.ones((32, 32)) @ jnp.ones((32, 32))
+                     ).block_until_ready()
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        starts = {}
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+            if plane.name.startswith("/host:CPU"):
+                for line in plane.lines:
+                    for e in line.events:
+                        starts.setdefault(e.name, []).append(e.start_ns)
+        (anchor,) = obs.events(name="profile.start")
+        assert "wall_ns" not in anchor
+        (anchor_ns,) = starts["profile.start"]
+        spans = obs.events(name="work.step")
+        assert len(spans) == len(starts["work.step"]) == 3
+        for rec, start_ns in zip(spans, sorted(starts["work.step"])):
+            mapped = (rec["ts"] - anchor["ts"]) * 1e9 + anchor_ns
+            assert abs(mapped - start_ns) < 1e6
+        (stop,) = obs.events(name="profile.stop")
+        mapped = (stop["ts"] - anchor["ts"]) * 1e9 + anchor_ns
+        assert abs(mapped - starts["profile.stop"][0]) < 1e6
+
     def test_obs_reset_clears_both(self):
         obs = Obs(annotate=False)
         obs.counter("c_total").inc()
@@ -362,6 +400,95 @@ class TestEngineTimelines:
         assert bare_syncs > 0
         assert sink_syncs == bare_syncs
         assert sink_toks == bare_toks  # sinks never perturb decode either
+
+
+class TestEngineSpans:
+    """Every host interval of a drive tick falls inside a named span, the
+    spans nest as documented, and they add no host sync."""
+
+    SLACK = 1e-9  # float rounding of ts + dur_s
+
+    def _run(self, cfg, params):
+        eng = _engine(cfg, params, cache=PrefixCache(
+            granularity=cfg.hla.chunk, budget_bytes=1 << 26))
+        base = np.random.RandomState(0).randint(2, cfg.vocab, 2 * 16 + 1)
+        reqs = [GenRequest(rid=i, prompt=np.concatenate([base, np.random.
+                RandomState(20 + i).randint(2, cfg.vocab, 3 + i)]),
+                max_new=6) for i in range(4)]
+        real = jax.device_get
+        stack = lambda: list(getattr(eng.obs.tracer._local, "stack", []))  # noqa: E731,E501
+        calls = []
+
+        def counting(x):
+            calls.append((stack(),
+                          len(eng.obs.events(name="engine.admit")),
+                          len(eng.obs.events(name="engine.decode_block"))))
+            return real(x)
+
+        jax.device_get = counting
+        try:
+            results = eng.run(reqs)
+        finally:
+            jax.device_get = real
+        assert all(r.status == "ok" for r in results)
+        return eng, calls
+
+    def _inside(self, child, parent):
+        return (parent["ts"] - self.SLACK <= child["ts"] and
+                child["ts"] + child["dur_s"]
+                <= parent["ts"] + parent["dur_s"] + self.SLACK)
+
+    def test_spans_nest_and_depth_agrees(self, cfg, params):
+        eng, _ = self._run(cfg, params)
+        spans = eng.obs.events(kind="span")
+        by = lambda n: [s for s in spans if s["name"] == n]  # noqa: E731
+        ticks, admits = by("engine.tick"), by("engine.admit")
+        assert ticks and len(admits) == 4
+        assert sum(t["admitted"] for t in ticks) == 4
+        assert eng.obs.registry.get("cache_hits_total").total() >= 1
+
+        def held(parent, name):
+            return [s for s in by(name) if self._inside(s, parent)]
+
+        for a in admits:
+            (look,) = held(a, "engine.cache_lookup")
+            (pre,) = held(a, "engine.prefill")
+            assert len(held(a, "engine.cache_insert")) <= 1
+            assert self._inside(look, pre)
+            assert (pre["depth"], look["depth"]) == (a["depth"] + 1,
+                                                     a["depth"] + 2)
+            assert pre["rid"] == a["rid"] and "hit_len" in look
+        for ins in by("engine.cache_insert"):
+            (a,) = [a for a in admits if self._inside(ins, a)]
+            assert ins["depth"] == a["depth"] + 1 and ins["nbytes"] > 0
+        for t in ticks:
+            assert t["depth"] == 0
+            assert len(held(t, "engine.admit")) == t["admitted"]
+            for name in ("engine.admit", "engine.decode_block",
+                         "engine.commit"):
+                assert all(s["depth"] == 1 for s in held(t, name))
+            blocks = held(t, "engine.decode_block")
+            assert len(blocks) <= 1
+            assert len(held(t, "engine.commit")) == len(blocks)
+        # every span of the engine lies inside some tick
+        for s in spans:
+            if s["name"] != "engine.tick":
+                assert any(self._inside(s, t) for t in ticks), s["name"]
+        hits = [s["hit_len"] for s in by("engine.cache_lookup")]
+        assert hits[0] == 0 and max(hits) == 2 * cfg.hla.chunk
+
+    def test_one_device_get_per_admission_and_per_block(self, cfg, params):
+        eng, calls = self._run(cfg, params)
+        n_admit = len(eng.obs.events(name="engine.admit"))
+        n_block = len(eng.obs.events(name="engine.decode_block"))
+        in_admit = [a for st, a, _ in calls if "engine.admit" in st]
+        in_block = [b for st, _, b in calls if "engine.decode_block" in st]
+        # the k-th call inside each kind happens while k spans are closed
+        assert in_admit == list(range(n_admit))
+        assert in_block == list(range(n_block))
+        assert len(calls) == n_admit + n_block
+        assert all("engine.prefill" in st or "engine.decode_block" in st
+                   for st, _, _ in calls)
 
 
 # -- checkpoint + training-loop integration ---------------------------------

@@ -334,5 +334,7 @@ def test_profile_capture_writes_trace_and_events(tmp_path):
     names = [e["name"] for e in obs.events(kind="event")]
     assert "profile.start" in names and "profile.stop" in names
     start = obs.events(name="profile.start")[0]
-    assert start["wall_ns"] > 0
+    stop = obs.events(name="profile.stop")[0]
+    assert 0 < start["ts"] < stop["ts"]  # anchors on the tracer's clock
+    assert start["profile_dir"] == str(prof)
     assert any(prof.rglob("*")), "no trace files written"
