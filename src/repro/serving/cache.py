@@ -25,17 +25,32 @@ Mechanics (DESIGN.md §16):
 * **Insertion** — on prefill completion the engine snapshots the state
   at the longest chunk-aligned prompt boundary and inserts it here.
   Snapshots are HOST trees (``StatePool.snapshot_slot(host=True)``
-  semantics): hundreds of cached prefixes consume RAM, never HBM.
+  semantics): hundreds of cached prefixes consume RAM, never HBM.  The
+  program that computes the boundary state also computes its
+  finiteness flag and ``tree_digest``; both ride the admission's one
+  ``device_get`` with the host copy, so the insert gate (a non-finite
+  state never becomes an entry) and the entry's digest cost no host
+  pass over the snapshot's bytes.
 * **Eviction** — LRU under an explicit byte budget.  Per-entry bytes
   are measured from the actual leaves and cross-checked against the
   analytic ``repro.obs.costs`` state-bytes model
   (``state_bytes_for(cfg)``), which is also how a budget is sized
   ("N cached prefixes" -> bytes).
-* **Integrity** — every entry carries a crc32 over its leaf bytes,
-  verified on every hit; a corrupt entry (``cache.corrupt`` fault
-  point, or a real bit flip) is dropped and the lookup falls through
-  to shorter prefixes / cold prefill.  A corrupt cache can cost
-  latency, never correctness.
+* **Integrity** — every entry carries ``tree_digest`` of its state: two
+  32-bit lanes, each a sum mod 2^32 over every 32-bit word of the leaves
+  of a bijective hash of the word keyed by its position and leaf index,
+  so any change to one word (a single flipped bit included) changes both
+  lanes, and changes to several words cancel with probability about
+  2^-32 per lane.  The engine verifies a hit on the device: the first
+  program that consumes the uploaded snapshot also returns the digest of
+  its input, so the check covers the host copy and the host-to-device
+  upload; after the admission's sync ``confirm`` compares it with the
+  stored digest.  On a mismatch (``cache.corrupt`` fault point, or a real
+  bit flip) the entry is dropped, nothing of the attempt is kept, and
+  the admission runs again, falling through to shorter prefixes / cold
+  prefill.  A cache used without an engine confirms a hit with
+  ``tree_digest`` of the host state.  A corrupt cache can cost latency,
+  never correctness.
 """
 
 from __future__ import annotations
@@ -45,6 +60,8 @@ import dataclasses
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..obs import Obs
@@ -70,20 +87,74 @@ def rolling_hashes(tokens: np.ndarray, lengths: List[int]) -> List[int]:
 
 def tree_bytes(tree) -> int:
     """Total bytes of a host state snapshot's leaves."""
-    import jax
-
     return int(sum(  # sync-point: host snapshot leaves, nbytes never syncs
         np.asarray(leaf).nbytes for leaf in jax.tree.leaves(tree)))
 
 
-def tree_checksum(tree) -> int:
-    """crc32 over every leaf's raw bytes (order = tree leaf order)."""
-    import jax
+def _mix32(x):
+    """A 32-bit integer hash (lowbias32) of uint32 ``x``, elementwise; a
+    bijection of the 32-bit words."""
+    x = x ^ (x >> 16)
+    x = x * np.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * np.uint32(0x846CA68B)
+    return x ^ (x >> 16)
 
-    crc = 0
+
+def _words(leaf) -> jax.Array:
+    """A leaf's bits as uint32 words of the leaf's own shape: 4-byte items
+    bitcast, 2- and 1-byte items bitcast to their unsigned type and
+    widened."""
+    unsigned = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[leaf.dtype.itemsize]
+    return jax.lax.bitcast_convert_type(leaf, unsigned).astype(jnp.uint32)
+
+
+def _positions(shape) -> jax.Array:
+    """Each item's row-major index mod 2^32, built from iotas over
+    ``shape`` itself: no reshape, so a sharded leaf stays sharded."""
+    pos = jnp.zeros(shape, jnp.uint32)
+    stride = 1
+    for d in reversed(range(len(shape))):
+        pos = pos + jax.lax.broadcasted_iota(jnp.uint32, shape, d) \
+            * np.uint32(stride % (1 << 32))
+        stride *= shape[d]
+    return pos
+
+
+@jax.jit
+def _tree_digest(tree) -> jax.Array:
+    lanes = jnp.zeros((2,), jnp.uint32)
+    for i, leaf in enumerate(jax.tree.leaves(tree)):
+        w = _words(leaf)
+        h = _mix32(_positions(w.shape) ^ _mix32(jnp.uint32(2 * i + 1)))
+        h2 = _mix32(h + np.uint32(0x9E3779B9))
+        lanes = lanes + jnp.stack([
+            jnp.sum(_mix32(w ^ h), dtype=jnp.uint32),
+            jnp.sum(_mix32(w + h2), dtype=jnp.uint32),
+        ])
+    return lanes
+
+
+def tree_digest(tree) -> jax.Array:
+    """uint32[2] integrity digest of a state tree, in one fused read.
+
+    Each lane is the sum mod 2^32, over every word of every leaf, of a
+    32-bit hash of the word keyed by a hash of its position and its
+    leaf's index (a different key per lane).  The hash is a bijection of
+    the word, so any change to one word, a single flipped bit included,
+    changes each lane; changes to several words cancel in a lane with
+    probability about 2^-32.  Jittable: the engine's admission programs
+    call it on device states, and on a mesh the reduction runs on each
+    shard.  Called on a host (numpy) tree it uploads the tree and runs
+    the same program.  Leaves of 1-, 2- and 4-byte dtypes only: a
+    program without x64 would narrow an 8-byte host leaf before reading
+    it, so those and bool are refused."""
     for leaf in jax.tree.leaves(tree):
-        crc = zlib.crc32(np.ascontiguousarray(leaf).tobytes(), crc)
-    return crc
+        dt = np.dtype(leaf.dtype)
+        if dt == np.bool_ or dt.itemsize not in (1, 2, 4):
+            raise TypeError(f"tree_digest reads 1-, 2- and 4-byte leaves, "
+                            f"not {dt}")
+    return _tree_digest(tree)
 
 
 def state_bytes_for(cfg, *, max_len: int = 64) -> int:
@@ -104,8 +175,12 @@ class CacheEntry:
     tokens: np.ndarray            # the exact prefix ids (collision guard)
     state: Any                    # host state pytree (numpy leaves)
     nbytes: int
-    checksum: int
+    digest: np.ndarray            # tree_digest(state) at insertion
     hits: int = 0
+
+    @property
+    def prefix_len(self) -> int:
+        return self.key[0]
 
 
 class PrefixCache:
@@ -163,7 +238,7 @@ class PrefixCache:
             "cache_evicted_bytes_total", "bytes LRU-evicted over budget")
         self._m_corrupt = m.counter(
             "cache_corrupt_dropped_total",
-            "entries dropped on checksum mismatch")
+            "entries dropped on digest mismatch")
         self._m_entries = m.gauge("cache_entries", "live entries")
         self._m_bytes = m.gauge("cache_bytes", "live host bytes")
         self._m_hit_toks = m.histogram(
@@ -215,8 +290,6 @@ class PrefixCache:
         the entry the lookup is about to return."""
         if self.faults is None:
             return
-        import jax
-
         if self.faults.hit("cache.corrupt") is None:
             return
         # snapshot leaves may be read-only (jax.device_get): corrupt a
@@ -229,15 +302,18 @@ class PrefixCache:
         entry.state = jax.tree.unflatten(treedef, flat)
 
     def lookup(self, tokens, *, max_prefix: Optional[int] = None
-               ) -> Optional[Tuple[int, Any]]:
-        """Longest verified cached prefix of ``tokens``.
+               ) -> Optional[CacheEntry]:
+        """Longest cached prefix of ``tokens`` whose stored ids match.
 
-        Returns ``(prefix_len, host_state)`` or None.  ``max_prefix``
-        caps the usable length (the engine passes ``len(prompt) - 1`` so
-        at least one suffix token remains to sample from).  Corrupt or
-        hash-colliding entries are dropped/skipped and the next-shorter
-        candidate is tried — a damaged cache degrades to cold prefill,
-        never to wrong tokens.
+        Returns the entry (``prefix_len``, host ``state``, stored
+        ``digest``) or None.  ``max_prefix`` caps the usable length (the
+        engine passes ``len(prompt) - 1`` so at least one suffix token
+        remains to sample from).  Hash-colliding entries are skipped.
+        The state's bytes are not read here: the caller digests the
+        state as it uses it (the engine on the device, a host caller with
+        ``tree_digest``) and settles the hit with ``confirm``; after a
+        failed confirm, looking up again falls through to the
+        next-shorter entry.
         """
         toks = np.asarray(tokens).reshape(-1)
         lengths = self._candidate_lengths(len(toks), max_prefix)
@@ -253,30 +329,45 @@ class PrefixCache:
             if not np.array_equal(entry.tokens, toks[:n]):
                 continue  # hash collision: content mismatch, keep probing
             self._corrupt_if_injected(entry)
-            if tree_checksum(entry.state) != entry.checksum:
-                self._drop(entry)
-                self._m_corrupt.inc()
-                self.obs.event("cache.corrupt_dropped", prefix_len=n)
-                continue
             self._entries.move_to_end(entry.key)  # LRU touch
-            entry.hits += 1
-            self._m_hits.inc()
-            self._m_hit_toks.observe(float(n))
-            self.obs.event("cache.hit", prefix_len=n, hits=entry.hits)
-            return n, entry.state
+            return entry
         self._m_misses.inc()
         return None
 
-    def insert(self, tokens, state) -> bool:
+    def confirm(self, entry: CacheEntry, digest) -> bool:
+        """Settle a hit: ``digest`` is ``tree_digest`` of the entry's
+        state as it was read.  A match counts the hit; a mismatch drops
+        the entry and counts it corrupt.  Returns whether they matched."""
+        if not np.array_equal(np.asarray(digest), entry.digest):
+            if self._entries.get(entry.key) is entry:
+                self._drop(entry)
+            self._m_corrupt.inc()
+            self.obs.event("cache.corrupt_dropped",
+                           prefix_len=entry.prefix_len)
+            return False
+        entry.hits += 1
+        self._m_hits.inc()
+        self._m_hit_toks.observe(float(entry.prefix_len))
+        self.obs.event("cache.hit", prefix_len=entry.prefix_len,
+                       hits=entry.hits)
+        return True
+
+    def insert(self, tokens, state, digest=None, *,
+               nbytes: Optional[int] = None) -> bool:
         """Insert a host state snapshot for the chunk-aligned prefix
-        ``tokens`` (insert-on-prefill-complete).  Refreshes LRU on
-        re-insertion of a live key.  Returns False when the entry was
-        rejected (misaligned length or larger than the whole budget)."""
+        ``tokens`` (insert-on-prefill-complete).  ``digest`` is
+        ``tree_digest(state)``, which the engine computes on the device
+        with the state; left out, it is computed here.  ``nbytes`` is
+        ``tree_bytes(state)`` where the caller already has it.  Refreshes
+        LRU on re-insertion of a live key.  Returns False when the entry
+        was rejected (misaligned length or larger than the whole
+        budget)."""
         toks = np.asarray(tokens).reshape(-1).astype(np.int64)
         n = len(toks)
         if n == 0 or n % self.granularity != 0:
             return False
-        nbytes = tree_bytes(state)
+        if nbytes is None:
+            nbytes = tree_bytes(state)
         if nbytes > self.budget_bytes:
             return False
         h = (rolling_hashes(toks, [n])[0] + self._ns_seed()) % _MOD
@@ -287,8 +378,10 @@ class PrefixCache:
             return True  # already cached: refresh recency, keep the entry
         if old is not None:
             self._drop(old)  # same key, different tokens: collision — replace
+        if digest is None:
+            digest = tree_digest(state)
         entry = CacheEntry(key=key, tokens=toks, state=state, nbytes=nbytes,
-                           checksum=tree_checksum(state))
+                           digest=np.asarray(digest))  # sync-point: 8 bytes
         self._entries[key] = entry
         self._lengths[n] += 1
         self.bytes += nbytes

@@ -67,12 +67,12 @@ import numpy as np
 from ..models import lm, seq_op
 from ..obs import Obs
 from ..runtime.faults import FaultPlan
-from .cache import PrefixCache, tree_bytes
+from .cache import PrefixCache, tree_bytes, tree_digest
 from .sampling import SamplingConfig, sample
 from .scheduler import Scheduler, SchedulerConfig
 from .spec import SpecConfig, build_drafter
 from .spec.verify import make_spec_round
-from .state_pool import StatePool, tree_finite, tree_finite_host
+from .state_pool import StatePool, tree_finite
 
 #: legacy ``Engine.stats`` keys -> unlabeled registry counters
 _STATS_COUNTERS = {
@@ -343,11 +343,15 @@ class Engine:
             )
             return tok, states, finite
 
-        def _prefill_from(params, prompt, positions, states, key, scfg):
+        def _prefill_from(params, prompt, positions, states, key, scfg,
+                          verify):
             # suffix prefill resumed from a cached prefix snapshot: exact
             # by the chunkwise carry identity (DESIGN.md §8/§16) — the
             # same ``lm_prefill(states=...)`` carry the spec verifier and
-            # the incremental-prefill tests already rely on
+            # the incremental-prefill tests already rely on.  With
+            # ``verify`` (input states uploaded from the cache, not a
+            # carry's output) the digest of the INPUT states checks them.
+            digest = tree_digest(states) if verify else None
             last_logits, states = lm.lm_prefill(
                 params, prompt, cfg, states=states, positions=positions
             )
@@ -355,18 +359,21 @@ class Engine:
             finite = tree_finite(states) & jnp.all(
                 jnp.isfinite(last_logits)
             )
-            return tok, states, finite
+            return tok, states, finite, digest
 
+        # the carries produce the chunk-boundary state the cache keeps,
+        # with its insert gate and digest; ``_carry_from`` also digests
+        # the uploaded snapshot it resumes from
         def _carry_cold(params, prompt):
-            # prompt[:aligned] -> the chunk-boundary state the cache keeps
             _, states = lm.lm_prefill(params, prompt, cfg)
-            return states
+            return states, tree_finite(states), tree_digest(states)
 
         def _carry_from(params, prompt, positions, states):
+            digest = tree_digest(states)
             _, states = lm.lm_prefill(
                 params, prompt, cfg, states=states, positions=positions
             )
-            return states
+            return states, digest, tree_finite(states), tree_digest(states)
 
         def _decode_block(params, states, tokens, positions, active, key,
                           sel, n_steps, scfgs):
@@ -408,7 +415,8 @@ class Engine:
             return states, tok, pos, toks, finite  # toks: (n_steps, slots)
 
         self._prefill = jax.jit(_prefill, static_argnames="scfg")
-        self._prefill_from = jax.jit(_prefill_from, static_argnames="scfg")
+        self._prefill_from = jax.jit(_prefill_from,
+                                     static_argnames=("scfg", "verify"))
         self._carry_cold = jax.jit(_carry_cold)
         self._carry_from = jax.jit(_carry_from)
         self._decode_block = jax.jit(
@@ -545,60 +553,20 @@ class Engine:
             )
         t0 = time.perf_counter()
         L = len(prompt_np)
-        hit_len = 0
-        insert_at = 0
-        carry_state = None
         with self.obs.span("engine.prefill", rid=req.rid, slot=slot,
                            prompt_len=L):
             self._raise_fault("engine.prefill")
             self.key, sub = jax.random.split(self.key)
             prompt = jnp.asarray(prompt_np[None])
-            done = 0  # tokens already summarized into carry_state
-            if self.cache is not None:
-                self._bind_faults()  # cache.corrupt may fire in lookup
-                with self.obs.span("engine.cache_lookup") as lab:
-                    found = self.cache.lookup(prompt_np, max_prefix=L - 1)
-                    if found is not None:
-                        hit_len, host_snap = found
-                        done, carry_state = hit_len, host_snap
-                    lab["hit_len"] = hit_len
-                aligned = self.cache.aligned_len(L)
-                if aligned > done:
-                    # advance to the chunk-aligned boundary first so its
-                    # state can be cached for future shared prefixes;
-                    # still chunk-parallel (one extra kernel call, both
-                    # calls together cover the prompt exactly once)
-                    seg = prompt[:, done:aligned]
-                    with self._mesh_ctx():
-                        if done == 0:
-                            carry_state = self._carry_cold(self.params, seg)
-                        else:
-                            carry_state = self._carry_from(
-                                self.params, seg,
-                                jnp.arange(done, aligned)[None],
-                                carry_state,
-                            )
-                    done, insert_at = aligned, aligned
-            with self._mesh_ctx():
-                if done == 0:
-                    first, state1, finite = self._prefill(
-                        self.params, prompt, sub, scfg
-                    )
-                else:
-                    first, state1, finite = self._prefill_from(
-                        self.params, prompt[:, done:],
-                        jnp.arange(done, L)[None], carry_state, sub, scfg,
-                    )
-                self.pool.write_slot(slot, state1)
-            # one sync per admission (TTFT endpoint); the health flag —
-            # and, on insertion admissions, the host copy of the
-            # boundary snapshot — ride it, and the span closes right
-            # after this existing sync
-            fetch = (first[0], finite) if insert_at == 0 else (
-                first[0], finite, carry_state)
-            got = jax.device_get(fetch)  # sync-point: admission TTFT endpoint
-            first_host, finite_host = got[0], got[1]
-        if not bool(finite_host):
+            # a hit the device found corrupt is dropped and the attempt
+            # redone with the same subkey; each redo has one entry fewer
+            # to hit, so this ends at a verified hit or a cold prefill
+            attempt = None
+            while attempt is None:
+                attempt = self._prefill_attempt(
+                    slot, prompt_np, prompt, sub, scfg)
+            hit_len, insert_at, got = attempt
+        if not bool(got["finite"]):
             self._m_quarantined.inc()
             self.pool.reset_slot(slot)
             raise RuntimeError(
@@ -606,13 +574,16 @@ class Engine:
                 "non-finite state — slot quarantined"
             )
         if insert_at:
-            with self.obs.span("engine.cache_insert",
-                               nbytes=tree_bytes(got[2])):
-                # insert-on-prefill-complete, AFTER the health gate: a
-                # poisoned boundary state must never become a cache entry
-                if tree_finite_host(got[2]):
-                    self.cache.insert(prompt_np[:insert_at], got[2])
-        first_tok = int(first_host)
+            snap, snap_finite, snap_digest = got["insert"]
+            with self.obs.span("engine.cache_insert") as lab:
+                lab["nbytes"] = nbytes = tree_bytes(snap)
+                # insert-on-prefill-complete, AFTER the health gate and
+                # on the device's own finiteness flag for the boundary
+                # state: a poisoned state must never become an entry
+                if bool(snap_finite):
+                    self.cache.insert(prompt_np[:insert_at], snap,
+                                      snap_digest, nbytes=nbytes)
+        first_tok = int(got["first"])
         ttft = time.perf_counter() - t0
         (self._m_ttft_hit if hit_len else self._m_ttft_cold).observe(ttft)
         self.tokens = self.tokens.at[slot, 0].set(first_tok)
@@ -648,6 +619,76 @@ class Engine:
             except Exception as e:  # drafter failure never fails admission
                 self._trip_breaker(f"drafter.admit failed: {e!r}")
         return first_tok
+
+    def _prefill_attempt(self, slot, prompt_np, prompt, sub, scfg):
+        """One try at an admission's prefill, from the cache lookup to
+        the admission's one sync.  Returns ``(hit_len, insert_at, got)``
+        with ``got`` the fetched first token, health flag and (when
+        ``insert_at``) the boundary snapshot with its finiteness flag and
+        digest.  Returns None when the device's digest of the cached
+        snapshot it resumed from did not match the entry's: the cache
+        has dropped the entry, the slot is reset, and nothing of the
+        attempt is kept."""
+        L = len(prompt_np)
+        hit = None
+        insert_at = 0
+        done = 0  # tokens already summarized into carry_state
+        carry_state = hit_digest = snap = None
+        if self.cache is not None:
+            self._bind_faults()  # cache.corrupt may fire in lookup
+            with self.obs.span("engine.cache_lookup") as lab:
+                hit = self.cache.lookup(prompt_np, max_prefix=L - 1)
+                if hit is not None:
+                    done, carry_state = hit.prefix_len, hit.state
+                lab["hit_len"] = done
+            aligned = self.cache.aligned_len(L)
+            if aligned > done:
+                # advance to the chunk-aligned boundary first so its
+                # state can be cached for future shared prefixes;
+                # still chunk-parallel (one extra kernel call, both
+                # calls together cover the prompt exactly once)
+                seg = prompt[:, done:aligned]
+                with self._mesh_ctx():
+                    if done == 0:
+                        carry_state, *gate = self._carry_cold(
+                            self.params, seg)
+                    else:
+                        carry_state, hit_digest, *gate = self._carry_from(
+                            self.params, seg,
+                            jnp.arange(done, aligned)[None], carry_state,
+                        )
+                snap = (carry_state, *gate)
+                done, insert_at = aligned, aligned
+        with self._mesh_ctx():
+            if done == 0:
+                first, state1, finite = self._prefill(
+                    self.params, prompt, sub, scfg
+                )
+            else:
+                # a hit no carry consumed is verified here
+                verify = hit is not None and hit_digest is None
+                first, state1, finite, digest = self._prefill_from(
+                    self.params, prompt[:, done:],
+                    jnp.arange(done, L)[None], carry_state, sub, scfg,
+                    verify=verify,
+                )
+                if verify:
+                    hit_digest = digest
+            self.pool.write_slot(slot, state1)
+        # one sync per attempt (TTFT endpoint): the health flag, the
+        # hit's device digest and, on insertion admissions, the host
+        # copy of the boundary snapshot with its gate and digest ride it
+        fetch = {"first": first[0], "finite": finite}
+        if hit is not None:
+            fetch["hit_digest"] = hit_digest
+        if insert_at:
+            fetch["insert"] = snap
+        got = jax.device_get(fetch)  # sync-point: admission TTFT endpoint
+        if hit is not None and not self.cache.confirm(hit,
+                                                      got["hit_digest"]):
+            self.pool.reset_slot(slot)
+            return None
+        return (hit.prefix_len if hit is not None else 0), insert_at, got
 
     def _commit(self, slot: int, toks) -> bool:
         """Append generated tokens to ``slot``'s stream with max_new/eos

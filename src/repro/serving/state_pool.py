@@ -40,21 +40,6 @@ def tree_finite(tree) -> jnp.ndarray:
     return ok
 
 
-def tree_finite_host(tree) -> bool:
-    """Host-side ``tree_finite`` over an already-fetched (numpy) snapshot
-    — no device work.  Gates prefix-cache insertion: a poisoned boundary
-    state must never become a cache entry.  bf16 leaves are upcast for
-    the check (ml_dtypes arrays are not numpy-``inexact``)."""
-    import numpy as np
-
-    for leaf in jax.tree.leaves(tree):
-        arr = np.asarray(leaf)
-        if jnp.issubdtype(arr.dtype, jnp.inexact) and \
-                not np.isfinite(arr.astype(np.float32)).all():
-            return False
-    return True
-
-
 def _slot_axis(shape_a, shape_b, slots: int) -> Optional[int]:
     """Axis along which ``shape_b`` (slots+1) grew out of ``shape_a`` (slots)."""
     if tuple(shape_a) == tuple(shape_b):

@@ -23,12 +23,14 @@ from repro.serving import (
     Engine,
     GenRequest,
     PrefixCache,
+    SamplingConfig,
     Scheduler,
     SchedulerConfig,
     StatePool,
     state_bytes_for,
 )
-from repro.serving.cache import rolling_hashes, tree_bytes, tree_checksum
+from repro.serving.cache import rolling_hashes, tree_bytes, tree_digest
+from repro.serving.state_pool import tree_finite
 from repro.serving.server import AsyncServer, collect
 
 
@@ -39,7 +41,16 @@ def _params(cfg, seed=0):
 def _tree(nbytes, seed=0):
     """A fake host state snapshot of exactly ``nbytes`` bytes."""
     rng = np.random.RandomState(seed)
-    return {"s": rng.randn(nbytes // 8).astype(np.float64)}
+    return {"s": rng.randn(nbytes // 4).astype(np.float32)}
+
+
+def _confirmed(cache, toks, **kw):
+    """``lookup`` settled by ``confirm`` with the host state's digest (the
+    engine digests on the device), falling through past corrupt entries."""
+    while (hit := cache.lookup(toks, **kw)) is not None:
+        if cache.confirm(hit, tree_digest(hit.state)):
+            return hit
+    return None
 
 
 def _req(rid, **kw):
@@ -80,16 +91,14 @@ def test_cache_longest_prefix_lookup(rng):
     assert cache.lookup(toks) is None  # empty cache: miss
     cache.insert(toks[:4], _tree(64, 1))
     cache.insert(toks[:12], _tree(64, 2))
-    n, state = cache.lookup(toks)
-    assert n == 12 and state["s"][0] == _tree(64, 2)["s"][0]
+    hit = _confirmed(cache, toks)
+    assert hit.prefix_len == 12 and hit.state["s"][0] == _tree(64, 2)["s"][0]
     # max_prefix caps the usable length (engine passes len(prompt) - 1)
-    n, _ = cache.lookup(toks, max_prefix=11)
-    assert n == 4
+    assert _confirmed(cache, toks, max_prefix=11).prefix_len == 4
     # a prompt diverging at token 5 only matches the 4-prefix
     other = toks.copy()
     other[5] += 1
-    n, _ = cache.lookup(other)
-    assert n == 4
+    assert _confirmed(cache, other).prefix_len == 4
     assert cache.stats()["hits"] == 3
 
 
@@ -114,8 +123,7 @@ def test_cache_hash_collision_never_returns_wrong_state(rng):
     cache._entries[forged_key] = entry
     cache._lengths[4] += 1
     assert cache.lookup(b) is None  # token guard rejects the forgery
-    n, _ = cache.lookup(a)
-    assert n == 4
+    assert cache.lookup(a).prefix_len == 4
 
 
 def test_cache_eviction_respects_byte_budget():
@@ -148,24 +156,133 @@ def test_cache_namespace_scopes_keys(rng):
 
 
 def test_cache_checksum_drops_corrupt_entry(rng):
-    """Injected corruption (``cache.corrupt``) and real bit rot both hit
-    the crc32 check: the entry is dropped, the lookup degrades to a miss
-    (cold prefill), never to wrong state."""
+    """Injected corruption (``cache.corrupt``) and real bit rot both fail
+    ``confirm`` against the stored digest: the entry is dropped and
+    counted, the lookup degrades to a miss (cold prefill), never to
+    wrong state."""
     plan = FaultPlan(FaultSpec(point="cache.corrupt", at=0))
     cache = PrefixCache(granularity=4, budget_bytes=1 << 20, faults=plan)
     toks = rng.randint(0, 100, 8)
     cache.insert(toks, _tree(64))
-    assert cache.lookup(toks) is None  # corrupted on first probe
+    # the lookup returns the hit unread; the digest of the state as it
+    # was served settles it
+    hit = cache.lookup(toks)  # corrupted on first probe
     assert plan.fired["cache.corrupt"] == 1
+    assert not cache.confirm(hit, tree_digest(hit.state))
     assert len(cache) == 0
+    assert cache.lookup(toks) is None
     assert cache.stats()["hits"] == 0
+    assert cache.obs.registry.get(
+        "cache_corrupt_dropped_total").total() == 1
 
     # organic corruption: mutate a leaf behind the cache's back
     cache2 = PrefixCache(granularity=4, budget_bytes=1 << 20)
     cache2.insert(toks, _tree(64))
     next(iter(cache2._entries.values())).state["s"][0] += 1.0
-    assert cache2.lookup(toks) is None
+    assert _confirmed(cache2, toks) is None
     assert len(cache2) == 0
+    assert cache2.obs.registry.get(
+        "cache_corrupt_dropped_total").total() == 1
+
+
+def _flip(leaf, word, bit):
+    """A copy of ``leaf`` with one bit of its ``word``-th item flipped."""
+    out = np.array(leaf)
+    view = out.view(np.dtype(f"uint{8 * out.dtype.itemsize}")).reshape(-1)
+    view[word] ^= view.dtype.type(1 << bit)
+    return out
+
+
+def _digest_tree(rng):
+    return {
+        "f32": rng.randn(3, 5, 7).astype(np.float32),
+        "bf16": np.asarray(rng.randn(4, 9), dtype=jnp.bfloat16),
+        "i32": rng.randint(-1000, 1000, (2, 11)).astype(np.int32),
+        "more": [rng.randn(6).astype(np.float32),
+                 np.asarray(rng.randn(2, 3), dtype=jnp.bfloat16)],
+    }
+
+
+@pytest.mark.parametrize("leaf", ["f32", "bf16", "i32"])
+def test_tree_digest_sees_every_single_bit_flip(leaf, rng):
+    """Flipping any one bit of the first, a middle or the last word of a
+    leaf changes both lanes of the digest."""
+    tree = _digest_tree(rng)
+    base = np.asarray(tree_digest(tree))
+    assert base.dtype == np.uint32 and base.shape == (2,)
+    arr = tree[leaf]
+    bits = 8 * arr.dtype.itemsize
+    for word in (0, arr.size // 2, arr.size - 1):
+        for bit in sorted({0, 1, bits // 2 - 1, bits // 2, bits - 2,
+                           bits - 1}):
+            flipped = dict(tree, **{leaf: _flip(arr, word, bit)})
+            got = np.asarray(tree_digest(flipped))
+            assert (got != base).all(), (leaf, word, bit)
+    # a nested leaf's flips count too, and the leaf's index is mixed in:
+    # the same words at another place in the tree read differently
+    nested = dict(tree, more=[_flip(tree["more"][0], 5, 31),
+                              tree["more"][1]])
+    assert (np.asarray(tree_digest(nested)) != base).all()
+    swapped = dict(tree, more=[tree["more"][0][::-1].copy(),
+                               tree["more"][1]])
+    assert (np.asarray(tree_digest(swapped)) != base).any()
+
+
+@pytest.mark.parametrize("bit", [29, 30, 31])
+def test_tree_digest_sees_paired_flips(bit, rng):
+    """Flipping the same high bit of two float32 words — in one leaf, or
+    across two leaves — changes both lanes: the per-word hash is not
+    linear, so such pairs do not cancel (flipping bit 31 adds 2^31 to
+    any odd multiple of the word)."""
+    tree = _digest_tree(rng)
+    base = np.asarray(tree_digest(tree))
+    f32, more = tree["f32"], tree["more"][0]
+    pairs = {
+        "one leaf": dict(tree, f32=_flip(_flip(f32, 0, bit), 1, bit)),
+        "far apart": dict(tree, f32=_flip(_flip(f32, 3, bit),
+                                          f32.size - 1, bit)),
+        "two leaves": dict(tree, f32=_flip(f32, 7, bit),
+                           more=[_flip(more, 2, bit), tree["more"][1]]),
+        "bits 30/31 across leaves": dict(
+            tree, f32=_flip(f32, 7, 30),
+            more=[_flip(more, 2, 31), tree["more"][1]]),
+    }
+    for name, flipped in pairs.items():
+        assert (np.asarray(tree_digest(flipped)) != base).all(), name
+
+
+def test_tree_digest_refuses_wide_and_bool_leaves():
+    """A program without x64 would narrow an 8-byte host leaf before the
+    digest read it, so 8-byte and bool leaves are refused, not
+    half-read."""
+    for leaf in (np.zeros(3, np.float64), np.zeros(3, np.int64),
+                 np.zeros(3, bool)):
+        with pytest.raises(TypeError):
+            tree_digest({"s": leaf})
+
+
+def test_tree_digest_host_equals_device_program(rng):
+    """The digest of a numpy tree is the digest a jitted program computes
+    of the same arrays on the device, alone or fused with other work."""
+    tree = _digest_tree(rng)
+    host = np.asarray(tree_digest(tree))
+    dev = jax.device_put(tree)
+    alone = jax.jit(lambda t: tree_digest(t))(dev)
+    fused = jax.jit(lambda t: (tree_finite(t), tree_digest(t),
+                               jax.tree.map(lambda x: x + 1, t)))(dev)
+    np.testing.assert_array_equal(np.asarray(alone), host)
+    np.testing.assert_array_equal(np.asarray(fused[1]), host)
+    assert bool(fused[0])
+    # and of a real state tree: what the engine digests is what a host
+    # snapshot of it digests
+    cfg = get_config("hla-1b", reduced=True)
+    states = jax.tree.map(
+        lambda x: jax.random.normal(jax.random.key(3), x.shape).astype(
+            x.dtype) if jnp.issubdtype(x.dtype, jnp.inexact) else x,
+        lm.lm_init_states(cfg, 1, 32))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(tree_digest)(states)),
+        np.asarray(tree_digest(jax.device_get(states))))
 
 
 def test_state_bytes_budget_sizing():
@@ -388,6 +505,87 @@ def test_cache_insertion_gated_on_finite_state(rng):
     assert len(cache) == 0
 
 
+def test_cache_corrupt_carry_hit_dropped_and_retried(rng):
+    """A corrupt hit on the hit-then-carry path (``_carry_from``): the
+    device's digest of the uploaded snapshot fails ``confirm``, the entry
+    is dropped, the carry it produced is never inserted, and the redone
+    admission streams what a cold engine does.  Nothing of the discarded
+    attempt reaches the outside: no token, no stream hook call, no TTFT
+    observation, no ``request.admitted`` event."""
+    cfg = get_config("hla-1b", reduced=True)
+    params = _params(cfg)
+    prefix = rng.randint(2, cfg.vocab, 13)
+    # 21 tokens: hit at 12, carry to the boundary at 20, suffix of one
+    longer = np.concatenate([prefix[:12], rng.randint(2, cfg.vocab, 9)])
+    reqs = [GenRequest(rid=0, prompt=prefix, max_new=6),
+            GenRequest(rid=1, prompt=longer, max_new=6)]
+    ref = Engine(cfg, params, slots=1, max_len=64, block=4).run(reqs)
+    # the boundary state a clean cold admission of ``longer`` inserts
+    clean = PrefixCache(granularity=4, budget_bytes=1 << 26)
+    Engine(cfg, params, slots=1, max_len=64, block=4, cache=clean).run(
+        [GenRequest(rid=1, prompt=longer, max_new=6)])
+    (want,) = clean._entries.values()
+
+    plan = FaultPlan(FaultSpec(point="cache.corrupt", at=0))
+    cache = PrefixCache(granularity=4, budget_bytes=1 << 26)
+    eng = Engine(cfg, params, slots=1, max_len=64, block=4, cache=cache,
+                 faults=plan)
+    inserted = []
+    real_insert = cache.insert
+
+    def spy(tokens, state, digest=None, **kw):
+        inserted.append((len(tokens), np.asarray(digest)))
+        return real_insert(tokens, state, digest, **kw)
+
+    cache.insert = spy
+    streamed = {0: [], 1: []}
+    eng.on_stream = lambda rid, toks, res: streamed[rid].extend(toks)
+    got = [eng.run([r])[0] for r in reqs]
+    assert [r.tokens for r in got] == [r.tokens for r in ref]
+    assert all(r.status == "ok" for r in got)
+    assert plan.fired["cache.corrupt"] == 1
+    reg = eng.obs.registry
+    assert reg.get("cache_corrupt_dropped_total").total() == 1
+    assert reg.get("cache_hits_total").total() == 0
+    # rid 0 inserted the 12-boundary; rid 1's only insert is the redo's
+    # cold carry to 20, bit for bit what a clean cold admission inserts
+    assert [n for n, _ in inserted] == [12, 20]
+    np.testing.assert_array_equal(inserted[1][1], want.digest)
+    assert len(cache) == 1
+    admitted = eng.obs.events("request.admitted")
+    assert [(e["rid"], e["cached_prefix"]) for e in admitted] == [
+        (0, 0), (1, 0)]
+    assert streamed == {r.rid: r.tokens for r in got}
+    assert reg.get("serving_ttft_seconds").count() == 2
+
+
+def test_cache_corrupt_retry_keeps_sampling_key(rng):
+    """Under a sampling law, a corrupt-and-retried admission reuses its
+    subkey: it streams the same tokens as an uncorrupted engine with the
+    same seed, whose admission resumed from the clean snapshot."""
+    cfg = get_config("hla-1b", reduced=True)
+    params = _params(cfg)
+    prompt = rng.randint(2, cfg.vocab, 13)
+    scfg = SamplingConfig("temperature", temperature=1.0)
+
+    def serve(faults):
+        eng = Engine(cfg, params, slots=1, max_len=64, block=4, seed=7,
+                     sampling=scfg, faults=faults,
+                     cache=PrefixCache(granularity=4, budget_bytes=1 << 26))
+        out = [eng.run([GenRequest(rid=i, prompt=prompt, max_new=8)])[0]
+               for i in range(2)]
+        return eng.obs.registry, out
+
+    plan = FaultPlan(FaultSpec(point="cache.corrupt", at=0))
+    reg, got = serve(plan)
+    clean_reg, want = serve(None)
+    assert plan.fired["cache.corrupt"] == 1
+    assert reg.get("cache_corrupt_dropped_total").total() == 1
+    assert clean_reg.get("cache_hits_total").total() == 1
+    assert all(r.status == "ok" for r in got)
+    assert [r.tokens for r in got] == [r.tokens for r in want]
+
+
 # --------------------------------------------------------------------------
 # engine + scheduler: expiry, priority, cancellation
 # --------------------------------------------------------------------------
@@ -468,11 +666,11 @@ def test_host_snapshot_roundtrip():
     snap = pool.snapshot_slot(1, host=True)
     assert all(isinstance(leaf, np.ndarray)
                for leaf in jax.tree.leaves(snap))
-    before = tree_checksum(snap)
+    before = np.asarray(tree_digest(snap))
     pool.reset_slot(1)
     pool.restore_slot(1, snap)
     restored = jax.device_get(pool.read_slot(1))
-    assert tree_checksum(restored) == before
+    np.testing.assert_array_equal(np.asarray(tree_digest(restored)), before)
     for a, b in zip(jax.tree.leaves(snap), jax.tree.leaves(restored)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

@@ -1,4 +1,5 @@
-"""Compile the main path's Pallas kernels for a described TPU v5e.
+"""Compile the main path's Pallas kernels (and the serving state's
+digest) for a described TPU v5e.
 
 Interpret mode runs the kernel bodies in Python, so it never sees what the
 TPU compiler refuses: blocks that break the (8, 128) tiling rule, scalar
@@ -128,6 +129,60 @@ def test_ahla_chunk_backward_compiles(one_chip):
     saved = tuple(_sds((BH, NC, D, D + 1), jnp.float32) for _ in range(2))
     fn = functools.partial(ahla_chunk_bwd_pallas, chunk=W)
     _compile(fn, QKV + [GAMMA, _sds((BH, N, D)), saved], one_chip)
+
+
+def test_state_digest_compiles_as_one_read(one_chip):
+    """The prefix cache's snapshot digest and finiteness flag, over one
+    sequence's whole hla-1b decode state (24 layers, 75.9 MB): reductions
+    that read the state and hold no state-sized temporary."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.serving.cache import tree_digest
+    from repro.serving.state_pool import tree_finite
+
+    shapes = jax.eval_shape(
+        lambda: lm.lm_init_states(get_config("hla-1b"), 1, 64))
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)
+    with jax.enable_x64(False):
+        mem = jax.jit(lambda s: (tree_finite(s), tree_digest(s))).lower(
+            args).compile().memory_analysis()
+    assert mem.argument_size_in_bytes > 75_000_000
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes // 32
+    assert mem.output_size_in_bytes < 4096
+
+
+def test_sharded_state_digest_stays_sharded(topo):
+    """The same digest and flag over one sequence's hla-1b decode state
+    with heads over a 1x4 ``model`` axis, as a sharded pool holds it: each
+    chip reduces its own shard, so nothing gathers the state and no chip
+    holds a state-sized temporary."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs import get_config
+    from repro.distributed import steps
+    from repro.serving.cache import tree_digest
+    from repro.serving.state_pool import tree_finite
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    with jax.enable_x64(False):
+        args = steps.state_specs(get_config("hla-1b"), 1, 64, mesh)
+        assert any(a.sharding.spec != jax.sharding.PartitionSpec()
+                   for a in jax.tree.leaves(args))
+        with mesh:
+            compiled = jax.jit(
+                lambda s: (tree_finite(s), tree_digest(s))).lower(
+                    args).compile()
+    assert "all-gather" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # per chip: a quarter of the 75.9 MB state, read in place, with no
+    # more scratch than the one-chip program may use (a gathered leaf
+    # alone would be 25 MB)
+    assert mem.argument_size_in_bytes < 75_000_000 // 2
+    assert mem.temp_size_in_bytes < 75_000_000 // 32
 
 
 @pytest.mark.parametrize("prompt_len", [300, 320])
