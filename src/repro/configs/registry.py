@@ -16,6 +16,7 @@ _ARCHS = {
     "rwkv6-7b": "rwkv6_7b",
     "internvl2-2b": "internvl2_2b",
     "hla-1b": "hla_1b",
+    "olmo-hybrid-7b": "olmo_hybrid_7b",
 }
 
 

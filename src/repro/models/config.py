@@ -37,6 +37,8 @@ class HLAConfig:
     force_pallas: bool = False  # run the Pallas kernels in interpret mode
     #   on any backend — how the CPU tests exercise the shard_map'd fused
     #   path on host devices; never set on the chip
+    d_key: int = 0  # per-head query/key width; 0 => the model's head_dim
+    d_value: int = 0  # per-head value/output width; 0 => head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,13 +66,15 @@ class ModelConfig:
     mamba: Optional[MambaConfig] = None
     qkv_bias: bool = False
     tie_embeddings: bool = False
-    rope_theta: float = 1e4
+    rope_theta: Optional[float] = 1e4  # None: no rotary embedding
     norm_eps: float = 1e-5
-    # hybrid pattern (jamba): layers come in groups; within a group, layer
-    # `attn_index` is attention(/HLA) and the rest are mamba; every
-    # `moe.every`-th layer of the group carries an MoE FFN.
-    group_size: int = 0  # 0 = uniform stack
-    attn_index: int = 0
+    # hybrid stacks: the sequence op at each position of a repeating
+    # period of layers, by registered name, with "mixer" standing for
+    # ``mixer`` (so a mixer override swaps those positions).  Jamba: 4
+    # mamba, "mixer", 3 mamba; Olmo Hybrid: 3 x "mixer", "attn".  Every
+    # ``moe.every``-th layer of a period carries an MoE FFN.  () = a
+    # uniform stack of ``mixer``.
+    layer_pattern: Tuple[str, ...] = ()
     # encoder-decoder (whisper): enc_layers > 0 activates the encoder
     enc_layers: int = 0
     enc_frames: int = 1500  # precomputed frame embeddings (stub frontend)
@@ -91,6 +95,11 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or (self.d_model // max(1, self.n_heads))
+
+    def hla_widths(self) -> Tuple[int, int]:
+        """(key, value) widths per head of the HLA mixer."""
+        return (self.hla.d_key or self.head_dim,
+                self.hla.d_value or self.head_dim)
 
     @property
     def attn_free(self) -> bool:

@@ -2,9 +2,9 @@
 
 Layers are stacked (leading "layers" axis on every param leaf) and applied
 with ``lax.scan`` — one layer body in the HLO regardless of depth (fast
-compiles, pipeline-friendly).  Hybrid (jamba) stacks scan over *groups* of
-``group_size`` layers (1 attention/HLA + rest mamba, MoE on alternate
-positions), unrolled inside the scan body.
+compiles, pipeline-friendly).  Hybrid stacks scan over *periods* of
+``cfg.layer_pattern`` (jamba: 1 attention/HLA + 7 mamba, MoE on alternate
+positions; Olmo Hybrid: 3 HLA2 + 1 softmax), unrolled inside the scan body.
 
 Every sequence-mixing sublayer is a registered ``seq_op.SequenceOp``
 (DESIGN.md §11): this module resolves ``cfg`` to op records ONCE and then
@@ -60,9 +60,11 @@ def layer_specs(cfg, op: seq_op.SequenceOp, use_moe: bool):
 
 def layer_apply(
     p, x, cfg, op: seq_op.SequenceOp, use_moe: bool, *,
-    positions=None, state=None, mode: str = "train",
+    positions=None, state=None, mode: str = "train", index=None,
 ):
-    """Returns (x, new_state, aux_loss)."""
+    """Returns (x, new_state, aux_loss).  With ``index`` (decode only),
+    ``state`` is the layer-stacked state and the op's ``step_at`` updates
+    layer ``index``'s part of it in place."""
     aux = jnp.zeros((), jnp.float32)
     if op.self_contained:
         x, new_state = op.forward(p, x, cfg, state=state)
@@ -70,7 +72,10 @@ def layer_apply(
 
     h = rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
     sub = p[op.param_key]
-    if mode == "decode" and op.streaming:
+    if index is not None:
+        y, new_state = op.step_at(sub, h, state, index, cfg,
+                                  positions=positions)
+    elif mode == "decode" and op.streaming:
         y, new_state = op.step(sub, h, state, cfg, positions=positions)
     else:
         # train: state is None, want_state False -> pure training path;
@@ -92,6 +97,26 @@ def layer_apply(
         y = mlp_apply(p["mlp"], h, cfg.mlp)
     x = x + y
     return x, new_state, aux
+
+
+def layer_decode_at(p, x, cfg, op: seq_op.SequenceOp, use_moe: bool, *,
+                    positions, stack, index):
+    """One decode layer against the layer-stacked ``stack`` at ``index``:
+    in place through the op's ``step_at``, else the layer's state sliced
+    out, stepped and written back."""
+    if op.step_at is not None and not op.self_contained:
+        return layer_apply(p, x, cfg, op, use_moe, positions=positions,
+                           state=stack, mode="decode", index=index)
+    st = jax.tree.map(
+        lambda s: jax.lax.dynamic_index_in_dim(s, index, keepdims=False),
+        stack)
+    x, st, aux = layer_apply(p, x, cfg, op, use_moe, positions=positions,
+                             state=st, mode="decode")
+    stack = jax.tree.map(
+        lambda s, n: jax.lax.dynamic_update_index_in_dim(
+            s, n.astype(s.dtype), index, 0),
+        stack, st)
+    return x, stack, aux
 
 
 def layer_init_state(cfg, op: seq_op.SequenceOp, B: int, max_len: int):
@@ -120,31 +145,46 @@ def _stack_specs(specs, L: int):
 
 
 def _group_layout(cfg):
-    """Hybrid (jamba) group layout: list of (op, use_moe) per position —
-    the configured mixer op at ``attn_index``, mamba elsewhere."""
-    mix_op = seq_op.op_for(cfg)
-    mamba_op = seq_op.get_op("mamba")
+    """Hybrid layout: list of (op, use_moe) per position of the period
+    ``cfg.layer_pattern`` ("mixer" is the configured mixer op)."""
+    period = len(cfg.layer_pattern)
+    if cfg.n_layers % period:
+        raise ValueError(
+            f"{cfg.name}: {cfg.n_layers} layers are not whole periods of "
+            f"the {period}-layer pattern {cfg.layer_pattern}"
+        )
     out = []
-    for i in range(cfg.group_size):
-        op = mix_op if i == cfg.attn_index else mamba_op
+    for i, name in enumerate(cfg.layer_pattern):
+        op = seq_op.op_for(cfg) if name == "mixer" else seq_op.get_op(name)
         use_moe = cfg.moe is not None and (i % cfg.moe.every == cfg.moe.every - 1)
         out.append((op, use_moe))
     return out
+
+
+def stack_ops(cfg):
+    """Every distinct sequence op of the stack."""
+    if cfg.layer_pattern:
+        return list({op.name: op for op, _ in _group_layout(cfg)}.values())
+    return [seq_op.op_for(cfg)]
 
 
 def needs_prealloc_states(cfg) -> bool:
     """True when prefill must write into preallocated states (KV caches /
     hybrid stacks) rather than building streaming state from scratch —
     derived from the ops' ``prealloc_state`` capability flag."""
-    if cfg.group_size:
-        return any(op.prealloc_state for op, _ in _group_layout(cfg))
-    return seq_op.op_for(cfg).prealloc_state
+    return any(op.prealloc_state for op in stack_ops(cfg))
+
+
+def holds_kv(cfg) -> bool:
+    """True when some layer's decode state grows with the context (a KV
+    cache): an op that is not ``streaming``."""
+    return any(not op.streaming for op in stack_ops(cfg))
 
 
 def lm_specs(cfg):
     specs = {"embed": embed_specs(cfg.vocab, cfg.d_model)}
-    if cfg.group_size:
-        n_groups = cfg.n_layers // cfg.group_size
+    if cfg.layer_pattern:
+        n_groups = cfg.n_layers // len(cfg.layer_pattern)
         group = {
             f"pos{i}": layer_specs(cfg, op, use_moe)
             for i, (op, use_moe) in enumerate(_group_layout(cfg))
@@ -189,8 +229,8 @@ def _maybe_remat(f, cfg):
 
 def lm_init_states(cfg, B: int, max_len: int):
     """Stacked decode states matching the scan layout."""
-    if cfg.group_size:
-        n_groups = cfg.n_layers // cfg.group_size
+    if cfg.layer_pattern:
+        n_groups = cfg.n_layers // len(cfg.layer_pattern)
         one = {
             f"pos{i}": layer_init_state(cfg, op, B, max_len)
             for i, (op, _) in enumerate(_group_layout(cfg))
@@ -211,7 +251,7 @@ def lm_state_axes(cfg):
     """
     from .param import Axes
 
-    if cfg.group_size:
+    if cfg.layer_pattern:
         one = {
             f"pos{i}": layer_state_axes(cfg, op)
             for i, (op, _) in enumerate(_group_layout(cfg))
@@ -252,7 +292,11 @@ def lm_apply(
         # from scratch.
         states = lm_init_states(cfg, B, n + 64)
 
-    if cfg.group_size:
+    if mode == "decode" and any(op.step_at is not None
+                                for op in stack_ops(cfg)):
+        x, new_states, aux = _decode_in_place(params, x, states, cfg,
+                                              positions)
+    elif cfg.layer_pattern:
         layout = _group_layout(cfg)
 
         def group_body(carry, inp):
@@ -305,13 +349,56 @@ def lm_apply(
     x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     if return_hidden:
         return x, (new_states if collect_state else None), aux
+    return _head(params, x, cfg), (new_states if collect_state else None), aux
+
+
+def _head(params, x, cfg):
+    """Final-normed hidden rows -> logits over the vocabulary."""
     if cfg.tie_embeddings:
-        logits = unembed_apply(params["embed"], x)
+        return unembed_apply(params["embed"], x)
+    return jnp.einsum(
+        "...d,dv->...v", x, params["unembed"]["kernel"].astype(x.dtype)
+    )
+
+
+def _decode_in_place(params, x, states, cfg, positions):
+    """Decode scan that carries the whole layer-stacked state tree and
+    hands each layer its index into it (``layer_decode_at``): ops with a
+    ``step_at`` (the KV cache) update their layer's part in place, so the
+    stack is never sliced out and written back per layer — the scan over
+    ``xs``/``ys`` would hold a second copy of every cache."""
+    if cfg.layer_pattern:
+        layout = _group_layout(cfg)
+        stacked = _cast_stack(params["groups"], cfg)
+        keys = [f"pos{i}" for i in range(len(layout))]
     else:
-        logits = jnp.einsum(
-            "...d,dv->...v", x, params["unembed"]["kernel"].astype(x.dtype)
-        )
-    return logits, (new_states if collect_state else None), aux
+        layout = [(seq_op.op_for(cfg), cfg.moe is not None)]
+        stacked = _cast_stack(params["layers"], cfg)
+        keys = [None]
+    pick = lambda tree, k: tree if k is None else tree[k]  # noqa: E731
+
+    def body(carry, inp):
+        x, aux, st = carry
+        x = constrain(x, ("batch", "seq", "embed"))
+        st = dict(st) if keys[0] is not None else st
+        for k, (op, use_moe) in zip(keys, layout):
+            x, new, a = layer_decode_at(
+                pick(inp["params"], k), x, cfg, op, use_moe,
+                positions=positions, stack=pick(st, k), index=inp["i"],
+            )
+            if k is None:
+                st = new
+            else:
+                st[k] = new
+            aux = aux + a
+        return (x, aux, st), None
+
+    n = jax.tree.leaves(stacked)[0].shape[0]
+    (x, aux, states), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), states),
+        {"params": stacked, "i": jnp.arange(n, dtype=jnp.int32)},
+    )
+    return x, states, aux
 
 
 def lm_prefill(params, tokens, cfg, *, states=None, positions=None):
@@ -324,11 +411,13 @@ def lm_prefill(params, tokens, cfg, *, states=None, positions=None):
     final prompt position (to sample the first generated token) plus the
     decode states.
     """
-    logits, states, _ = lm_apply(
+    hidden, states, _ = lm_apply(
         params, tokens, cfg, states=states, positions=positions,
-        mode="prefill",
+        mode="prefill", return_hidden=True,
     )
-    return logits[:, -1], states
+    # the head at the last position only: full-vocabulary logits of a
+    # long prompt would be the largest tensor of the program
+    return _head(params, hidden[:, -1], cfg), states
 
 
 def lm_score_block(params, tokens, cfg, *, states, positions):

@@ -50,13 +50,14 @@ from .param import Axes, Spec
 
 
 def mixer_specs(cfg):
-    d, H, Hk, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    d, H, Hk = cfg.d_model, cfg.n_heads, cfg.n_kv_heads
+    dk, dv = cfg.hla_widths()
     s = {
-        "wq": dense_specs(d, H * dh, axes=("embed", "q_heads_flat"), bias=cfg.qkv_bias),
-        "wk": dense_specs(d, Hk * dh, axes=("embed", "kv_heads_flat"), bias=cfg.qkv_bias),
-        "wv": dense_specs(d, Hk * dh, axes=("embed", "kv_heads_flat"), bias=cfg.qkv_bias),
-        "wo": dense_specs(H * dh, d, axes=("q_heads_flat", "embed")),
-        "out_scale": Spec((H, dh), ("q_heads", "head_dim"), init="ones"),
+        "wq": dense_specs(d, H * dk, axes=("embed", "q_heads_flat"), bias=cfg.qkv_bias),
+        "wk": dense_specs(d, Hk * dk, axes=("embed", "kv_heads_flat"), bias=cfg.qkv_bias),
+        "wv": dense_specs(d, Hk * dv, axes=("embed", "kv_heads_flat"), bias=cfg.qkv_bias),
+        "wo": dense_specs(H * dv, d, axes=("q_heads_flat", "embed")),
+        "out_scale": Spec((H, dv), ("q_heads", "head_dim"), init="ones"),
     }
     if cfg.hla.decay == "learned":
         s["decay_a"] = Spec((H,), ("q_heads",), init="constant", const=3.0)
@@ -75,11 +76,12 @@ def _gamma(p, cfg, B):
 
 def _project(p, x, cfg):
     B, n, _ = x.shape
-    H, Hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = dense_apply(p["wq"], x).reshape(B, n, H, dh).swapaxes(1, 2)
-    k = dense_apply(p["wk"], x).reshape(B, n, Hk, dh).swapaxes(1, 2)
-    v = dense_apply(p["wv"], x).reshape(B, n, Hk, dh).swapaxes(1, 2)
-    q = q * (dh**-0.5)
+    H, Hk = cfg.n_heads, cfg.n_kv_heads
+    dk, dv = cfg.hla_widths()
+    q = dense_apply(p["wq"], x).reshape(B, n, H, dk).swapaxes(1, 2)
+    k = dense_apply(p["wk"], x).reshape(B, n, Hk, dk).swapaxes(1, 2)
+    v = dense_apply(p["wv"], x).reshape(B, n, Hk, dv).swapaxes(1, 2)
+    q = q * (dk**-0.5)
     if Hk != H:  # GQA: broadcast KV heads to query heads
         rep = H // Hk
         k = jnp.repeat(k, rep, axis=1)
@@ -249,7 +251,7 @@ def _sublayer_forward(core_fwd):
         o, st = core_fwd(q, k, v, gamma, hc, state=state,
                          want_state=want_state, kw=kw)
         o = _out_norm(p, o.astype(x.dtype), cfg)
-        o = o.swapaxes(1, 2).reshape(B, n, cfg.n_heads * cfg.head_dim)
+        o = o.swapaxes(1, 2).reshape(B, n, -1)
         o = constrain(o, ("batch", None, "q_heads_flat"))
         return dense_apply(p["wo"], o), st
 
@@ -271,9 +273,9 @@ def _sublayer_step(core_step):
         gamma = _gamma(p, cfg, B)
         kw = dict(normalize=hc.normalize, eps=1e-6)
         state, o = core_step(state, q1, k1, v1, gamma, hc, kw)
-        o = o[..., None, :]  # (B, H, 1, dh)
+        o = o[..., None, :]  # (B, H, 1, dv)
         o = _out_norm(p, o.astype(x_t.dtype), cfg)
-        o = o.swapaxes(1, 2).reshape(B, 1, cfg.n_heads * cfg.head_dim)
+        o = o.swapaxes(1, 2).reshape(B, 1, -1)
         return dense_apply(p["wo"], o), state
 
     return step
@@ -281,9 +283,9 @@ def _sublayer_step(core_step):
 
 def _mixer_init(core_init):
     def init_state(cfg, B, *, max_len=0, dtype=None):
-        dh = cfg.head_dim
+        dk, dv = cfg.hla_widths()
         return core_init(
-            (B, cfg.n_heads), dh, dh,
+            (B, cfg.n_heads), dk, dv,
             jnp.float32 if dtype is None else dtype,
         )
 

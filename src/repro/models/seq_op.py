@@ -95,6 +95,12 @@ class SequenceOp:
     # own specs/init_state).  See ``models/gla.py`` for the worked
     # example and ``docs/DESIGN.md`` §15 for the contract.
     cost_model: Optional[Callable[..., Dict[str, float]]] = None
+    # optional one-token decode on the LAYER-STACKED state tree:
+    # ``step_at(p, x_t, stack, index, cfg, *, positions) -> (y, stack)``
+    # updates layer ``index``'s part of ``stack`` in place (a scatter into
+    # the stacked leaves), so a decode scan can carry a state too large to
+    # slice out and back per layer (a KV cache) — see ``lm.lm_apply``
+    step_at: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
     # key the operator's params live under inside a layer's param dict
     # (kept stable for existing checkpoints: HLA family -> "mixer")
     param_key: Optional[str] = None

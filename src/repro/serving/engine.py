@@ -44,10 +44,16 @@ The serving pattern the paper's O(1)-state decode enables (DESIGN.md §8):
   cooldown/half-open re-probe to recover.  All failure modes are
   injectable deterministically via ``runtime.faults.FaultPlan``.
 
-KV-cache (softmax / hybrid) archs are rejected: their pooled cache keeps a
-*shared* scalar ``length``, so per-slot admission would need per-slot
-lengths threaded through attention — a follow-up, not a serving-engine
-concern (the HLA family is the paper's point).
+* **KV caches beside streaming state.**  A stack with softmax layers
+  (a hybrid such as Olmo Hybrid's 3 HLA2 : 1 softmax) keeps, per slot,
+  a KV cache of ``max_len`` positions with its own length next to the
+  O(1) HLA leaves.  Admission writes the prompt's cache into the slot;
+  each decode step appends every row at its own length and masks each
+  row to it.  The pool is donated through admission writes and decode
+  blocks, so each cache is updated in place: one copy of the pool, never
+  two.  Prefix caching and speculative decoding are refused for such
+  stacks (a cached prefix would have to carry its KV rows; a rollback
+  would have to truncate them).
 """
 
 from __future__ import annotations
@@ -64,7 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import lm, seq_op
+from ..models import lm
 from ..obs import Obs
 from ..runtime.faults import FaultPlan
 from .cache import PrefixCache, tree_bytes, tree_digest
@@ -202,24 +208,26 @@ class Engine:
         cache: Optional[PrefixCache] = None,
         sched: Optional[SchedulerConfig] = None,
     ):
-        # serveability is a REGISTRY capability, not a hardcoded tuple:
-        # any op registered with streaming=True (O(1) decode state) admits
-        # per-slot continuous batching; KV-cache ops (attn) and hybrid
-        # stacks share a pooled scalar length across slots and cannot.
-        op = seq_op.op_for(cfg)
-        if not op.streaming or cfg.group_size:
+        # serveability is structural: every leaf of the stack's decode
+        # state must have a slot axis (checked on the pool below).  KV
+        # caches (ops that are not ``streaming``) serve without prefix
+        # caching or speculation.
+        ops = lm.stack_ops(cfg)
+        self._kv = lm.holds_kv(cfg)
+        if self._kv and (cache is not None or spec is not None):
+            kv_ops = [op.name for op in ops if not op.streaming]
             raise ValueError(
-                "Engine serves streaming-state ops "
-                f"{seq_op.streaming_op_names()}; op {op.name!r} "
-                f"(group_size={cfg.group_size}) decodes from a KV cache "
-                "whose pooled scalar length is shared across slots — "
-                "continuous batching needs per-slot lengths"
+                f"{cfg.name}: ops {kv_ops} decode from KV caches, which "
+                "the Engine serves without a prefix cache (cache=) or "
+                "speculative decoding (spec=): a cached prefix would have "
+                "to carry its KV rows and a rollback would have to "
+                "truncate them"
             )
-        if spec is not None and not op.spec_decodable:
+        if spec is not None and not all(op.spec_decodable for op in ops):
             raise ValueError(
-                f"op {op.name!r} is not registered spec_decodable: its "
-                "state cannot be snapshot/rolled back for speculative "
-                "verification"
+                f"ops {[op.name for op in ops]} are not all registered "
+                "spec_decodable: their state cannot be snapshot/rolled "
+                "back for speculative verification"
             )
         self.cfg = cfg
         self.params = params
@@ -255,8 +263,13 @@ class Engine:
             )
         self.pool = StatePool(
             lambda n: lm.lm_init_states(cfg, n, max_len), slots,
-            shardings=pool_shardings,
+            shardings=pool_shardings, donate=self._kv,
         )
+        if None in self.pool.slot_axes:
+            raise ValueError(
+                f"{cfg.name}: the decode state holds leaves shared across "
+                "slots, so slots cannot be admitted and freed one by one"
+            )
         self.tokens = jnp.zeros((slots, 1), jnp.int32)
         self.positions = jnp.zeros((slots, 1), jnp.int32)
         self.active = np.zeros(slots, bool)
@@ -294,6 +307,9 @@ class Engine:
             "wall-clock in decode blocks / spec rounds")
         self._m_prompt_toks = m.counter(
             "serving_prompt_tokens_total", "prompt tokens prefilled")
+        self._m_kv_toks = m.counter(
+            "serving_kv_tokens_total",
+            "KV positions attended by decode steps, over active slots")
         self._m_gen_toks = m.counter(
             "serving_generated_tokens_total", "tokens in terminal streams")
         self._m_requests = m.counter(
@@ -333,8 +349,16 @@ class Engine:
 
         pool = self.pool
 
+        kv = self._kv
+
         def _prefill(params, prompt, key, scfg):
-            last_logits, states = lm.lm_prefill(params, prompt, cfg)
+            # a KV stack's prefill fills caches over the prompt's
+            # positions, which the pool write puts at the head of the
+            # slot's max_len rows
+            states = (lm.lm_init_states(cfg, 1, prompt.shape[1]) if kv
+                      else None)
+            last_logits, states = lm.lm_prefill(params, prompt, cfg,
+                                                states=states)
             tok = sample(last_logits, key, scfg)
             # admission health check: rides the sync that already fetches
             # the first sampled token (no extra round trip)
@@ -419,8 +443,11 @@ class Engine:
                                      static_argnames=("scfg", "verify"))
         self._carry_cold = jax.jit(_carry_cold)
         self._carry_from = jax.jit(_carry_from)
+        # a KV pool is donated through the block: the caches are updated
+        # in place, and ``step_block`` replaces the pool with the output
         self._decode_block = jax.jit(
-            _decode_block, static_argnames=("n_steps", "scfgs")
+            _decode_block, static_argnames=("n_steps", "scfgs"),
+            donate_argnames=("states",) if kv else (),
         )
 
         if spec is not None:
@@ -554,7 +581,7 @@ class Engine:
         t0 = time.perf_counter()
         L = len(prompt_np)
         with self.obs.span("engine.prefill", rid=req.rid, slot=slot,
-                           prompt_len=L):
+                           prompt_len=L, kv_len=L if self._kv else 0):
             self._raise_fault("engine.prefill")
             self.key, sub = jax.random.split(self.key)
             prompt = jnp.asarray(prompt_np[None])
@@ -924,9 +951,12 @@ class Engine:
         active_dev = jnp.asarray(self.active)
         uniq = tuple(sorted(set(self._slot_scfg), key=repr))
         sel = jnp.asarray([uniq.index(c) for c in self._slot_scfg])
+        kv_tokens = self._kv_tokens(n_steps)
+        self._m_kv_toks.inc(kv_tokens)
         t0 = time.perf_counter()
         with self.obs.span("engine.decode_block", steps=n_steps,
-                           slots_active=int(self.active.sum())):
+                           slots_active=int(self.active.sum()),
+                           kv_tokens=kv_tokens):
             with self._mesh_ctx():
                 states, tok, pos, toks, finite = self._decode_block(
                     self.params, self.pool.states, self.tokens,
@@ -952,6 +982,19 @@ class Engine:
                     continue
                 self._commit(s, toks_host[:, s])
             self._sweep_deadlines()
+
+    def _kv_tokens(self, n_steps: int) -> int:
+        """KV positions the block's steps attend, over the active slots of
+        a KV stack (0 for streaming stacks): a slot whose next token sits
+        at position p attends p + 1 positions, then p + 2, ..."""
+        if not self._kv:
+            return 0
+        total = 0
+        for s in range(self.pool.slots):
+            if self.active[s]:
+                p = len(self._slot_req[s].prompt) + len(self._slot_out[s]) - 1
+                total += n_steps * (p + 1) + n_steps * (n_steps - 1) // 2
+        return total
 
     # -- speculative decode -------------------------------------------------
 

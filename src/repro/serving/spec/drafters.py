@@ -166,12 +166,12 @@ class HLADrafter(Drafter):
     def __init__(self, cfg, params=None, *, slots: int, max_len: int,
                  k: int, sampling: SamplingConfig = SamplingConfig(),
                  seed: int = 0, mesh=None):
-        op = seq_op.op_for(cfg)
-        if not op.streaming or cfg.group_size:
+        ops = lm.stack_ops(cfg)
+        if not all(op.streaming for op in ops):
             raise ValueError(
-                f"HLADrafter needs a streaming-state op "
+                f"HLADrafter needs streaming-state ops "
                 f"{seq_op.streaming_op_names()}, got "
-                f"op={op.name!r} group_size={cfg.group_size}"
+                f"{[op.name for op in ops]}"
             )
         self.cfg = cfg
         self.k = k

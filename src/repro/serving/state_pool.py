@@ -7,8 +7,7 @@ silently skips leaves without a slot axis at position 1.  ``StatePool``
 instead *derives* each leaf's slot axis structurally: it abstractly
 evaluates the state template at ``slots`` and ``slots + 1`` and takes the
 (unique) axis whose extent changed.  Leaves whose shape does not depend on
-the slot count (e.g. the KV cache's shared scalar ``length``) get no slot
-axis and are left untouched by per-slot writes.
+the slot count get no slot axis and are left untouched by per-slot writes.
 
 Admission and eviction are **scatter-based**: one
 ``lax.dynamic_update_slice`` per leaf at the detected axis — no full-tree
@@ -72,21 +71,21 @@ class StatePool:
     """
 
     def __init__(self, template_fn: Callable[[int], Any], slots: int,
-                 shardings=None):
+                 shardings=None, donate: bool = False):
         if slots < 1:
             raise ValueError("need at least one slot")
         self.slots = slots
         self._template_fn = template_fn
         self.shardings = shardings
-        if shardings is None:
-            self.states = template_fn(slots)
-        else:
-            # born sharded: never materialize the full pool replicated on
-            # one device (the transient could exceed a single device's HBM
-            # even when the sharded steady state fits)
-            self.states = jax.jit(
-                lambda: template_fn(slots), out_shardings=shardings
-            )()
+        # built by one program: op by op, a stacked template holds a
+        # layer's leaf, its copy and its broadcast at once (with KV caches
+        # that overran the chip's memory), and sharded, the full pool
+        # would be materialised replicated on one device first
+        self.states = jax.jit(
+            lambda: template_fn(slots), out_shardings=shardings
+        )()
+        self._zeros = jax.jit(
+            lambda: jax.tree.map(jnp.zeros_like, template_fn(1)))
         shapes_n = jax.eval_shape(lambda: template_fn(slots))
         shapes_n1 = jax.eval_shape(lambda: template_fn(slots + 1))
         leaves_n, self._treedef = jax.tree.flatten(shapes_n)
@@ -130,11 +129,16 @@ class StatePool:
                 )
             return out
 
+        # ``donate``: a write updates the pool's buffers in place (a pool
+        # of KV caches has no room for a second copy); the arrays of
+        # ``states`` before a write are then gone
+        donated = (0,) if donate else ()
         if shardings is None:
-            self._write = jax.jit(_write)
+            self._write = jax.jit(_write, donate_argnums=donated)
         else:
             self._write = jax.jit(
-                _write, out_shardings=jax.tree.leaves(shardings)
+                _write, out_shardings=jax.tree.leaves(shardings),
+                donate_argnums=donated,
             )
         self._read = jax.jit(_read)
 
@@ -179,8 +183,7 @@ class StatePool:
 
     def reset_slot(self, slot: int) -> None:
         """Zero a slot (eviction)."""
-        zeros = jax.tree.map(jnp.zeros_like, self.empty_slot_state())
-        self.write_slot(slot, zeros)
+        self.write_slot(slot, self._zeros())
 
     # -- health -------------------------------------------------------------
 

@@ -374,9 +374,15 @@ def test_gla_sharded_serving_matches_single_device():
 
 
 def test_engine_rejects_non_streaming_op():
-    from repro.serving import Engine
+    """A KV-cache op serves per slot, but not with a prefix cache or
+    speculative decoding."""
+    from repro.serving import Engine, PrefixCache, SpecConfig
 
     cfg = _cfg_for("attn")
     params = init_params(lm.lm_specs(cfg), jax.random.key(0))
-    with pytest.raises(ValueError, match="streaming-state ops"):
-        Engine(cfg, params, slots=2, max_len=32)
+    Engine(cfg, params, slots=2, max_len=32)
+    with pytest.raises(ValueError, match="KV caches"):
+        Engine(cfg, params, slots=2, max_len=32,
+               cache=PrefixCache(granularity=8, budget_bytes=1 << 20))
+    with pytest.raises(ValueError, match="KV caches"):
+        Engine(cfg, params, slots=2, max_len=32, spec=SpecConfig(k=2))

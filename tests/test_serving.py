@@ -100,8 +100,28 @@ def test_state_pool_slot_axis_regression():
     assert (np.asarray(pool.states["a"]) == 0).all()
 
 
+def test_state_pool_is_built_by_one_program():
+    """The pool, and the zeros a reset writes, each come out of one traced
+    program: built op by op, a stacked template holds a layer's leaf, its
+    copy and its broadcast at once, which overran the chip's memory with
+    a hybrid stack's KV caches."""
+    concrete = []
+
+    def make(n):
+        x = jnp.zeros((2, n, 4))
+        concrete.append(not isinstance(x, jax.core.Tracer))
+        return {"s": jnp.broadcast_to(x[None], (3,) + x.shape)}
+
+    pool = StatePool(make, 3)
+    pool.write_slot(1, {"s": jnp.ones((3, 2, 1, 4))})
+    pool.reset_slot(1)
+    assert concrete and not any(concrete)
+    assert pool.states["s"].shape == (3, 2, 3, 4)
+    assert not np.asarray(pool.states["s"]).any()
+
+
 def test_state_pool_lm_states():
-    """Pool over real stacked LM decode states; KV scalar length is shared."""
+    """Pool over real stacked LM decode states; KV lengths are per slot."""
     cfg = get_config("hla-1b", reduced=True)
     pool = StatePool(lambda n: lm.lm_init_states(cfg, n, 32), slots=4)
     # every HLA2 state leaf is (layers, slot, head, ...) -> slot axis 1
@@ -109,8 +129,8 @@ def test_state_pool_lm_states():
 
     cfg_sm = cfg.replace(mixer="softmax")
     pool_sm = StatePool(lambda n: lm.lm_init_states(cfg_sm, n, 32), slots=4)
-    # KVCache.length is stacked (layers,) — slot-independent => no slot axis
-    assert None in pool_sm.slot_axes and 1 in pool_sm.slot_axes
+    # KVCache.length is (layers, slot): every leaf has its slot axis at 1
+    assert all(ax == 1 for ax in pool_sm.slot_axes)
 
 
 # --------------------------------------------------------------------------
@@ -170,9 +190,16 @@ def test_engine_ragged_prompts_and_throughput_stats(rng):
 
 
 def test_engine_rejects_kv_cache_archs():
+    """KV-cache stacks serve per slot, but without a prefix cache or
+    speculative decoding: both are refused at construction."""
+    from repro.serving import PrefixCache, SpecConfig
+
     cfg = get_config("hla-1b", reduced=True).replace(mixer="softmax")
-    with pytest.raises(ValueError, match="per-slot lengths"):
-        Engine(cfg, None, slots=2, max_len=16)
+    with pytest.raises(ValueError, match="prefix cache"):
+        Engine(cfg, None, slots=2, max_len=16,
+               cache=PrefixCache(granularity=8, budget_bytes=1 << 20))
+    with pytest.raises(ValueError, match="speculative"):
+        Engine(cfg, None, slots=2, max_len=16, spec=SpecConfig(k=2))
 
 
 # --------------------------------------------------------------------------

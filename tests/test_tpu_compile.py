@@ -119,6 +119,83 @@ def test_hla2_decode_step_compiles(one_chip):
     _compile(step, [state, tok, tok, tok, _sds(rows, jnp.float32)], one_chip)
 
 
+# Olmo-Hybrid-7B's linear-attention layers (configs/olmo_hybrid_7b.py):
+# 30 heads, key width 96, value width 192, chunk 128
+OH, OD, ODV = 30, 96, 192
+
+
+def _hla2_state(rows, d, dv):
+    return tuple(_sds(rows + s, jnp.float32)
+                 for s in ((d, d), (d, dv), (d,), (d, dv), (d,)))
+
+
+def test_hla2_prefill_with_state_compiles_at_key_96_value_192(one_chip):
+    """Serving prefill of one 8,192-token prompt at Olmo Hybrid's linear
+    widths: key width 96 is no multiple of the 128 lanes."""
+    n = 8192
+    qkv = [_sds((OH, n, OD)), _sds((OH, n, OD)), _sds((OH, n, ODV))]
+
+    def prefill(q, k, v, g, state):
+        return hla2_chunk_pallas(q, k, v, g, chunk=W, initial_state=state)
+
+    _compile(prefill, qkv + [_sds((OH,), jnp.float32),
+                             _hla2_state((OH,), OD, ODV)], one_chip)
+
+
+def test_hla2_decode_step_compiles_at_key_96_value_192(one_chip):
+    rows = (8, OH)  # 8 slots x 30 heads
+
+    def step(state, q, k, v, g):
+        return hla2_step_pallas(state, q, k, v, g)
+
+    _compile(step, [_hla2_state(rows, OD, ODV), _sds(rows + (OD,)),
+                    _sds(rows + (OD,)), _sds(rows + (ODV,)),
+                    _sds(rows, jnp.float32)], one_chip)
+
+
+def test_hybrid_decode_block_updates_kv_caches_in_place(one_chip,
+                                                        monkeypatch):
+    """The Engine's decode block for one period of Olmo Hybrid at its
+    published widths (3 HLA2 layers and 1 softmax layer), 8 slots of
+    2,048 positions: the pool is donated and aliased to the output, and
+    the block holds no second copy of the KV caches (a slice-out and
+    write-back per layer, or a relaid-out cache, would need one)."""
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.distributed import steps
+    from repro.models import lm, mixer
+    from repro.models.param import abstract_params
+    from repro.serving import Engine, SamplingConfig
+
+    monkeypatch.setattr(mixer, "_pallas_enabled", lambda hc: hc.use_pallas)
+    cfg = get_config("olmo-hybrid-7b").replace(n_layers=4)
+    slots, max_len = 8, 2048
+    with jax.enable_x64(False):
+        eng = Engine(cfg, None, slots=slots, max_len=max_len, block=8,
+                     sampling=SamplingConfig("greedy"))
+        put = lambda t: jax.tree.map(  # noqa: E731
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), t)
+        params = put(abstract_params(steps.model_specs(cfg)))
+        states = put(jax.eval_shape(
+            lambda: lm.lm_init_states(cfg, slots, max_len)))
+        col = put(jax.ShapeDtypeStruct((slots, 1), jnp.int32))
+        args = (params, states, col, col,
+                put(jax.ShapeDtypeStruct((slots,), jnp.bool_)),
+                put(jax.eval_shape(lambda: jax.random.key(0))),
+                put(jax.ShapeDtypeStruct((slots,), jnp.int32)))
+        compiled = eng._decode_block.lower(
+            *args, n_steps=8, scfgs=(eng.sampling,)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    kv = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+             for a in jax.tree.leaves(states["pos3"]))
+    assert kv > 250_000_000
+    assert mem.alias_size_in_bytes >= kv
+    assert mem.temp_size_in_bytes < kv // 4
+
+
 @pytest.mark.parametrize("save", [False, True], ids=["fwd", "fwd_saved"])
 def test_ahla_chunk_forward_compiles(one_chip, save):
     fn = functools.partial(ahla_chunk_pallas, chunk=W, save_chunk_states=save)
