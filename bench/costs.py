@@ -20,7 +20,10 @@ A product of ``m x k`` by ``k x n`` is ``2 m k n`` operations.  The
 backward pass is counted as twice the forward (each product has two
 adjoint products); recomputation is not counted.  A decode step costs
 ``4 d^2 + 10 d dv`` per head (state update and read-out, as the
-streaming recurrence of Sec. 4 does it).
+streaming recurrence of Sec. 4 does it), and moves per head its float32
+state (S, C, G, m, h) read and written, its q, k, v read, its output
+written and its float32 decay read: counted per row, so the count does
+not depend on how the states are laid out around the rows.
 """
 
 from __future__ import annotations
@@ -51,6 +54,14 @@ def hla2_bwd_flops(rows: int, n: int, w: int, d: int, dv: int) -> float:
 
 def decode_step_flops(rows: int, d: int, dv: int) -> float:
     return rows * (4.0 * d * d + 10.0 * d * dv)
+
+
+def decode_step_bytes(rows: int, d: int, dv: int, itemsize: int) -> int:
+    """Bytes a decode step over ``rows`` heads moves: per row the state
+    read and written, q and k (``d``), v and the output (``dv``) at
+    ``itemsize``, and the decay."""
+    state = 4 * (d * d + 2 * d * dv + 2 * d)
+    return rows * (2 * state + itemsize * (2 * d + 2 * dv) + 4)
 
 
 def matmul_params(c: dict) -> int:

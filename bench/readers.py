@@ -44,12 +44,19 @@ def serve_mfu(obs) -> Optional[float]:
     return 100.0 * least / (obs["chips"] * tr.window_ns / 1e9)
 
 
+def operand_bytes(outs, operands) -> float:
+    """A call's operands read once and its outputs written once."""
+    return float(trace_reduce.nbytes(outs) + trace_reduce.nbytes(operands))
+
+
 def kernel_roofline(obs, match: Callable[[list], bool],
-                    flops: Callable[[list, dict], float]) -> Optional[float]:
+                    flops: Callable[[list, dict], float],
+                    byts: Callable[[list, list], float] = operand_bytes
+                    ) -> Optional[float]:
     """Least time of every traced call of one kernel over the device time
     of those calls.  ``match`` tells the kernel by its output shapes;
-    ``flops`` counts a call's operations from them.  A call's bytes are
-    its operands read once and its outputs written once."""
+    ``flops`` counts a call's operations from them, ``byts`` its bytes
+    from its output and operand shapes."""
     tr = obs.get("trace")
     if tr is None:
         return None
@@ -59,10 +66,9 @@ def kernel_roofline(obs, match: Callable[[list], bool],
         outs = trace_reduce.output_shapes(ev.name)
         if not outs or not match(outs):
             continue
-        byts = trace_reduce.nbytes(outs) + trace_reduce.nbytes(
-            trace_reduce.operand_shapes(ev.name))
+        b = byts(outs, trace_reduce.operand_shapes(ev.name))
         least += max(flops(outs, obs["cfg"]) / pk["flops_per_s"],
-                     byts / pk["bytes_per_s"])
+                     b / pk["bytes_per_s"])
         dur += ev.dur / 1e9
     return None if dur == 0.0 else 100.0 * least / dur
 
@@ -95,16 +101,33 @@ def is_decode_step(outs) -> bool:
     return len(outs) == 6 and len(outs[0][1]) == 3 and outs[0][1][1] == 1
 
 
+def _key_width(outs) -> int:
+    """Key width of a chunk or decode call: the last dimension of its
+    first state output, S (rows, dk, dk)."""
+    return outs[1][1][-1]
+
+
 def hla2_fwd_flops(outs, cfg) -> float:
     R, n, dv = _rows_tokens(outs)
-    return costs.hla2_fwd_flops(R, n, cfg["chunk"], dv, dv)
+    return costs.hla2_fwd_flops(R, n, cfg["chunk"], _key_width(outs), dv)
 
 
 def hla2_bwd_flops(outs, cfg) -> float:
-    R, n, dv = _rows_tokens(outs)
-    return costs.hla2_bwd_flops(R, n, cfg["chunk"], dv, dv)
+    """From dq (rows, n, dk) and dv (rows, n, dv)."""
+    R, n, dk = _rows_tokens(outs)
+    return costs.hla2_bwd_flops(R, n, cfg["chunk"], dk, outs[2][1][2])
 
 
 def decode_flops(outs, cfg) -> float:
     R, _, dv = _rows_tokens(outs)
-    return costs.decode_step_flops(R, dv, dv)
+    return costs.decode_step_flops(R, _key_width(outs), dv)
+
+
+def decode_step_bytes(outs, operands) -> float:
+    """A decode step's bytes per row: ``R`` rows from its token output
+    (R, 1, dv), the widths from that output and S.  The states' leading
+    dimension is left out, so a call that updates one layer of a stack
+    of states in place counts the rows it touches, not the whole stack."""
+    R, _, dv = _rows_tokens(outs)
+    return float(costs.decode_step_bytes(
+        R, _key_width(outs), dv, trace_reduce.DTYPE_BYTES[outs[0][0]]))

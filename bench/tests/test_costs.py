@@ -76,3 +76,27 @@ def test_kernel_classes_by_output_shapes():
                        ("dec", dec)):
         hits = [k for k, f in table.items() if f(outs)]
         assert hits == [name]
+
+
+# rows, tokens, key and value widths of the hybrid's linear layers
+R, N, DK, DV = 30, 512, 96, 192
+STATES = [("f32", (R, DK, DK)), ("f32", (R, DK, DV)), ("f32", (R, 1, DK)),
+          ("f32", (R, DK, DV)), ("f32", (R, 1, DK))]
+
+
+@pytest.mark.parametrize("outs,match,read,want", [
+    pytest.param([("bf16", (R, N, DV))] + STATES, readers.is_hla2_prefill,
+                 readers.hla2_fwd_flops, costs.hla2_fwd_flops, id="prefill"),
+    pytest.param([("bf16", (R, N, DV))] + STATES + [("f32", (R, 4, DK, DK))],
+                 readers.is_hla2_train_fwd, readers.hla2_fwd_flops,
+                 costs.hla2_fwd_flops, id="train-fwd"),
+    pytest.param([("bf16", (R, N, DK))] * 2 + [("bf16", (R, N, DV)),
+                                               ("f32", (R, 1, 1))],
+                 readers.is_hla2_bwd, readers.hla2_bwd_flops,
+                 costs.hla2_bwd_flops, id="bwd"),
+])
+def test_chunk_kernels_count_key_and_value_widths(outs, match, read, want):
+    """At key width 96 and value width 192 each chunk kernel counts its
+    operations at both widths, read from its output shapes."""
+    assert match(outs)
+    assert read(outs, {"chunk": 128}) == want(R, N, 128, DK, DV)

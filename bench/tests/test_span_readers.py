@@ -1,11 +1,12 @@
-"""Readers of the engine's host spans, on hand-built trace summaries, and
-the operation labels of kernels named by ``pallas_call(name=...)``."""
+"""Readers of the engine's host spans, on hand-built trace summaries, the
+operation labels of kernels named by ``pallas_call(name=...)``, and the
+decode step's count per row."""
 
 import os
 
 import pytest
 
-from bench import readers, run, trace_reduce as tr
+from bench import costs, readers, run, trace_reduce as tr
 
 METRICS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "metrics")
@@ -105,3 +106,60 @@ def test_a_named_kernel_keeps_its_label_shapes_and_match():
     assert tr.KERNEL in new
     assert readers.is_decode_step(tr.output_shapes(new))
     assert not readers.is_hla2_prefill(tr.output_shapes(new))
+
+
+def _decode_call(rows, dk, dv, stack=None):
+    """HLO text of a decode-step call over ``rows`` heads at key width
+    ``dk`` and value width ``dv``.  With ``stack``, the call updates one
+    layer of a state stack of ``stack`` rows in place: its state operands
+    and outputs are the whole stack, and the layer index comes first."""
+    n = stack or rows
+    states = [f"f32[{n},{dk},{dk}]", f"f32[{n},{dk},{dv}]", f"f32[{n},1,{dk}]",
+              f"f32[{n},{dk},{dv}]", f"f32[{n},1,{dk}]"]
+    ins = ([f"s32[1]{{0}} %layer"] if stack else []) + [
+        f"f32[{rows},1,1]{{2,1,0}} %gamma", f"bf16[{rows},1,{dk}]{{2,1,0}} %q",
+        f"bf16[{rows},1,{dk}]{{2,1,0}} %k", f"bf16[{rows},1,{dv}]{{2,1,0}} %v",
+    ] + [f"{s}{{2,1,0}} %s{i}" for i, s in enumerate(states)]
+    outs = [f"bf16[{rows},1,{dv}]{{2,1,0}}"] + [s + "{2,1,0}" for s in states]
+    return (f"%hla2_decode_step.4 = ({', '.join(outs)}) custom-call("
+            f"{', '.join(ins)}), custom_call_target=\"tpu_custom_call\"")
+
+
+def _decode_share(text, seconds):
+    summ = _summ({}, [])
+    summ.kernels = [tr.Event(text, 0.0, seconds * 1e9)]
+    return run.load_reader(os.path.join(METRICS, "decode_step_roofline.py"))(
+        {"trace": summ, "device_kind": "TPU v5 lite", "cfg": {}})
+
+
+@pytest.mark.parametrize("rows,dk,dv", [(512, 128, 128), (240, 96, 192)])
+@pytest.mark.parametrize("stack", [None, 24], ids=["per-layer", "in-stack"])
+def test_decode_step_counts_bytes_and_operations_per_row(rows, dk, dv, stack):
+    """A decode step's count is its rows' work: a call that updates one
+    layer of a stack of states in place reads the bytes, operations and
+    roofline share of a per-layer call over the same rows and time, and
+    those bytes are the per-layer call's operands plus outputs."""
+    per_layer = _decode_call(rows, dk, dv)
+    text = _decode_call(rows, dk, dv, stack and stack * rows)
+    outs, ins = tr.output_shapes(text), tr.operand_shapes(text)
+    assert readers.is_decode_step(outs)
+    assert len(ins) == (10 if stack else 9)
+    old = tr.nbytes(tr.output_shapes(per_layer)) + tr.nbytes(
+        tr.operand_shapes(per_layer))
+    assert readers.decode_step_bytes(outs, ins) == old
+    if (rows, dk, dv) == (512, 128, 128):
+        assert old == 202_901_504
+    assert readers.decode_flops(outs, {}) == costs.decode_step_flops(
+        rows, dk, dv)
+    seconds = 306e-6
+    assert _decode_share(text, seconds) == _decode_share(per_layer, seconds) \
+        == pytest.approx(100 * old / 819e9 / seconds)
+
+
+def test_the_real_decode_call_reads_its_operands_and_outputs():
+    """The trace's own text of a per-layer call at 512 rows: the count per
+    row is its operands read once and its outputs written once."""
+    text = DECODE_OP.format(name="hla2_decode_step.4")
+    outs, ins = tr.output_shapes(text), tr.operand_shapes(text)
+    assert readers.decode_step_bytes(outs, ins) == \
+        readers.operand_bytes(outs, ins) == 202_901_504
